@@ -24,6 +24,7 @@ Cost model per instruction:
 from __future__ import annotations
 
 import dataclasses
+import math
 import re
 from typing import Dict, List, Optional, Tuple
 
@@ -73,13 +74,13 @@ def _shape_elems(dims: str) -> int:
     return elems
 
 
-def _shapes(text: str) -> List[Tuple[int, int]]:
-    """All (elems, bytes) array-shape tokens in ``text``."""
-    out = []
-    for dtype, dims in _SHAPE_RE.findall(text):
-        elems = _shape_elems(dims)
-        out.append((elems, elems * _DTYPE_BYTES[dtype]))
-    return out
+def _shapes(text: str) -> List[Tuple[str, str]]:
+    """All (dtype, dims) array-shape tokens in ``text``."""
+    return _SHAPE_RE.findall(text)
+
+
+def _nbytes(shapes: List[Tuple[str, str]]) -> int:
+    return sum(_shape_elems(dims) * _DTYPE_BYTES[dtype] for dtype, dims in shapes)
 
 
 def _split_type_and_op(rhs: str) -> Tuple[str, str, int]:
@@ -120,17 +121,17 @@ def _balanced(text: str, open_idx: int) -> Tuple[str, str]:
     return text[open_idx + 1:], ""
 
 
-_OPERAND_RE = re.compile(
-    r"\b(" + "|".join(sorted(_DTYPE_BYTES, key=len, reverse=True))
-    + r")\[([0-9,]*)\](?:\{[^}]*\})?\s+%([^\s,()]+)")
+_OPERAND_NAME_RE = re.compile(r"%([^\s,()]+)")
 
 
 @dataclasses.dataclass
 class _Instr:
     op: str
     name: str = ""
+    out_shapes: Tuple[Tuple[str, str], ...] = ()     # (dtype, dims) per array
     out_elems: int = 0
     out_bytes: int = 0
+    operand_names: Tuple[str, ...] = ()
     operand_bytes: int = 0
     operand_info: Tuple[Tuple[str, int], ...] = ()   # (name, bytes) per operand
     param_index: Optional[int] = None                # for op == "parameter"
@@ -142,9 +143,13 @@ class _Instr:
     branches: Tuple[str, ...] = ()
     group_size: Optional[int] = None
     label: str = ""
+    attrs: str = ""
 
 
 def _parse_instr(line: str) -> Optional[_Instr]:
+    """One instruction line.  Operands are recorded by name only: the HLO
+    text prints an operand's type inline in some XLA versions and not in
+    others, so ``_resolve_operands`` reads them from the defining lines."""
     s = line.strip()
     if s.startswith("ROOT "):
         s = s[5:]
@@ -155,15 +160,11 @@ def _parse_instr(line: str) -> Optional[_Instr]:
     if paren < 0:
         return None
     operands, attrs = _balanced(rhs, paren)
-    ins = _Instr(op=op, name=lhs.strip().lstrip("%"))
-    out = _shapes(type_str)
-    ins.out_elems = sum(e for e, _ in out)
-    ins.out_bytes = sum(b for _, b in out)
-    opshapes = _shapes(operands)
-    ins.operand_bytes = sum(b for _, b in opshapes)
-    ins.operand_info = tuple(
-        (m.group(3), _shape_elems(m.group(2)) * _DTYPE_BYTES[m.group(1)])
-        for m in _OPERAND_RE.finditer(operands))
+    ins = _Instr(op=op, name=lhs.strip().lstrip("%"), attrs=attrs)
+    ins.out_shapes = tuple(_shapes(type_str))
+    ins.out_elems = sum(_shape_elems(dims) for _, dims in ins.out_shapes)
+    ins.out_bytes = _nbytes(list(ins.out_shapes))
+    ins.operand_names = tuple(_OPERAND_NAME_RE.findall(operands))
     if op == "parameter":
         mp = re.match(r"\s*(\d+)", operands)
         if mp:
@@ -172,32 +173,7 @@ def _parse_instr(line: str) -> Optional[_Instr]:
     m = re.search(r'op_name="([^"]+)"', attrs)
     ins.label = f"{op} {type_str}" + (f"  {m.group(1)}" if m else "")
 
-    if op == "dot":
-        contraction = 1
-        mdims = re.search(r"lhs_contracting_dims=\{([0-9,]*)\}", attrs)
-        if mdims and opshapes:
-            mlhs = _SHAPE_RE.search(operands)
-            lhs_dims = ([int(d) for d in mlhs.group(2).split(",")]
-                        if mlhs and mlhs.group(2) else [])
-            for d in (mdims.group(1).split(",") if mdims.group(1) else []):
-                di = int(d)
-                if di < len(lhs_dims):
-                    contraction *= lhs_dims[di]
-        ins.flops = 2.0 * ins.out_elems * contraction
-    elif op == "convolution":
-        kernel_elems = opshapes[1][0] if len(opshapes) > 1 else 1
-        out_features = 1
-        mlab = re.search(r"dim_labels=[^_]+_([0-9a-z]+)->", attrs)
-        if mlab:
-            klabels = mlab.group(1)
-            o_pos = klabels.find("o")
-            mker = list(_SHAPE_RE.finditer(operands))
-            if o_pos >= 0 and len(mker) > 1 and mker[1].group(2):
-                kdims = [int(d) for d in mker[1].group(2).split(",")]
-                if o_pos < len(kdims):
-                    out_features = max(kdims[o_pos], 1)
-        ins.flops = 2.0 * ins.out_elems * kernel_elems / out_features
-    elif op == "while":
+    if op == "while":
         mb = re.search(r"body=%([^\s,]+)", attrs)
         mc = re.search(r"condition=%([^\s,]+)", attrs)
         ins.while_body = mb.group(1) if mb else None
@@ -228,6 +204,40 @@ def _parse_instr(line: str) -> Optional[_Instr]:
             if mg:
                 ins.group_size = int(mg.group(1))
     return ins
+
+
+def _resolve_operands(ins: _Instr, table: Dict[str, Tuple[Tuple[str, str], ...]]) -> None:
+    """Operand bytes and matmul-class flops from the operands' defining
+    shapes (``table``: instruction name -> output shapes)."""
+    opshapes = [table.get(name, ()) for name in ins.operand_names]
+    ins.operand_info = tuple(
+        (name, _nbytes(list(shapes)))
+        for name, shapes in zip(ins.operand_names, opshapes))
+    ins.operand_bytes = sum(b for _, b in ins.operand_info)
+
+    def dims(i: int) -> List[int]:
+        if i >= len(opshapes) or not opshapes[i] or not opshapes[i][0][1]:
+            return []
+        return [int(d) for d in opshapes[i][0][1].split(",")]
+
+    if ins.op == "dot":
+        contraction = 1
+        mdims = re.search(r"lhs_contracting_dims=\{([0-9,]*)\}", ins.attrs)
+        lhs_dims = dims(0)
+        for d in (mdims.group(1).split(",") if mdims and mdims.group(1) else []):
+            if int(d) < len(lhs_dims):
+                contraction *= lhs_dims[int(d)]
+        ins.flops = 2.0 * ins.out_elems * contraction
+    elif ins.op == "convolution":
+        kdims = dims(1)
+        kernel_elems = math.prod(kdims)
+        out_features = 1
+        mlab = re.search(r"dim_labels=[^_]+_([0-9a-z]+)->", ins.attrs)
+        if mlab:
+            o_pos = mlab.group(1).find("o")
+            if 0 <= o_pos < len(kdims):
+                out_features = max(kdims[o_pos], 1)
+        ins.flops = 2.0 * ins.out_elems * kernel_elems / out_features
 
 
 @dataclasses.dataclass
@@ -317,6 +327,10 @@ def parse_module(hlo_text: str) -> HloModule:
             current_raw = None
     if entry is None and comps:
         entry = next(iter(comps))
+    table = {ins.name: ins.out_shapes for instrs in comps.values() for ins in instrs}
+    for instrs in comps.values():
+        for ins in instrs:
+            _resolve_operands(ins, table)
     return HloModule(comps=comps, raw=raw_lines, entry=entry,
                      num_partitions=num_partitions)
 

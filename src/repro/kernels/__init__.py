@@ -1,13 +1,22 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
-"""Shared pallas compatibility helpers for the kernel implementations."""
+"""Pallas kernels for the compute hot spots, each with ops (jit'd wrappers)
+and ref (pure-jnp oracle) modules."""
+
+from typing import Optional
+
+import jax
 
 
-def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` was ``TPUCompilerParams`` before jax 0.5;
-    construct whichever this jax ships."""
-    from jax.experimental.pallas import tpu as pltpu
+def pallas_interpret(interpret: Optional[bool] = None) -> bool:
+    """Interpret mode for a Pallas call: the one place that decides it.
 
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
+    ``None`` (every wrapper's default) compiles natively on a TPU backend and
+    interprets everywhere else.  ``False`` compiles natively even here, which
+    is what an ahead-of-time compile for a described TPU does.  ``True`` on a
+    TPU backend is refused: the interpreter would run on the chip silently and
+    report nothing about the kernel."""
+    on_tpu = jax.default_backend() == "tpu"
+    if interpret is None:
+        return not on_tpu
+    if interpret and on_tpu:
+        raise ValueError("Pallas interpret mode requested on a TPU backend")
+    return interpret

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
+from repro.kernels import pallas_interpret
 
 NEG_INF = -1e30
 
@@ -98,7 +98,7 @@ def flash_attention_fwd_pallas(
     sm_scale: Optional[float] = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     bh, sq, d = q.shape
     skv = k.shape[1]
@@ -137,10 +137,10 @@ def flash_attention_fwd_pallas(
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(qp, kp, vp)
     return out[:, :sq]
 
@@ -154,7 +154,7 @@ def flash_attention_pallas(
     sm_scale: Optional[float] = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """GQA wrapper: broadcasts KV heads, flattens (B, H) for the kernel."""
     b, hq, sq, d = q.shape

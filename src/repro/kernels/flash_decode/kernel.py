@@ -9,9 +9,10 @@ Two kernels:
 * ``paged_flash_decode_pallas`` — paged cache.  The KV pool stays put in
   HBM ((n_pages, Hk, page, d)); the per-sequence page table and valid
   lengths ride in as scalar-prefetch operands, and the grid iterates
-  (B, Hk, page groups).  Each program resolves its logical pages to
-  physical pages through the prefetched table and streams them through
-  VMEM — the (B, Hk, P*page, d) gather the jnp fallback materializes
+  (B, Hk, page groups).  Each page slot of a group is a BlockSpec input
+  whose index map resolves the logical page to its physical page through
+  the prefetched table, so the pipeline DMAs exactly the group's pages
+  into VMEM — the (B, Hk, P*page, d) gather the jnp fallback materializes
   never exists.  Groups entirely past a sequence's valid length are
   predicated off with ``pl.when`` (skipped by the scalar unit on TPU).
   An optional rotary/PE operand pair (q_pe, kpe pool) serves the MLA
@@ -33,7 +34,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
+from repro.kernels import pallas_interpret
 
 NEG_INF = -1e30
 
@@ -41,6 +42,7 @@ NEG_INF = -1e30
 # (scores: q @ k^T) / contract q's last with v's first (context: p @ v)
 _DOT_QK = (((1,), (1,)), ((), ()))
 _DOT_PV = (((1,), (0,)), ((), ()))
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _decode_kernel(
@@ -94,7 +96,7 @@ def flash_decode_pallas(
     *,
     sm_scale: Optional[float] = None,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     b, h, s, d = k_cache.shape
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
@@ -125,8 +127,8 @@ def flash_decode_pallas(
             pltpu.VMEM((1,), jnp.float32),
             pltpu.VMEM((1,), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        interpret=pallas_interpret(interpret),
     )(lens, qf, kf, vf)
     return out.reshape(b, h, d)
 
@@ -146,21 +148,19 @@ def _paged_decode_kernel(
       len_ref  (B,) int32 SMEM          — valid positions incl. new token
       q_ref    (1, 1, G, dk) VMEM block
       [qpe_ref (1, 1, G, dr) VMEM block]           (has_pe)
-      k_ref    (n_pages, Hk, page, dk) ANY — whole pool, loaded per page
-      [kpe_ref (n_pages, Hk, page, dr) ANY]        (has_pe)
-      v_ref    (n_pages, Hk, page, dv) ANY
+      ppp page tiles (1, 1, page, dk) of the K pool, [ppp of the PE pool],
+      then ppp of the V pool: page i of this group, fetched by its BlockSpec
+      through the page table
       o_ref    (1, 1, G, dv) VMEM block
-      acc_ref (G, dv) f32, m_ref (G,) f32, l_ref (G,) f32 scratch.
+      acc (G, dv), m (G, 1), l (G, 1) f32 running-stat scratch.
     """
-    if has_pe:
-        (pt_ref, len_ref, q_ref, qpe_ref, k_ref, kpe_ref, v_ref, o_ref) = refs[:8]
-        acc_ref, m_ref, l_ref = refs[8:]
-    else:
-        (pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref) = refs[:6]
-        acc_ref, m_ref, l_ref = refs[6:]
-        qpe_ref = kpe_ref = None
+    ppp = pages_per_program
+    n_pools = 3 if has_pe else 2
+    pt_ref, len_ref, q_ref = refs[:3]
+    qpe_ref = refs[3] if has_pe else None
+    tiles = refs[3 + has_pe: 3 + has_pe + n_pools * ppp]
+    o_ref, acc_ref, m_ref, l_ref = refs[3 + has_pe + n_pools * ppp:]
     b = pl.program_id(0)
-    h = pl.program_id(1)
     grp = pl.program_id(2)
 
     @pl.when(grp == 0)
@@ -170,45 +170,39 @@ def _paged_decode_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
 
     length = len_ref[b]
-    blk = pages_per_program * page_size
-    start = grp * blk
+    start = grp * ppp * page_size
+
+    def block(p):
+        # pool p's pages of this group as one (ppp*page, d) f32 block
+        group = tiles[p * ppp: (p + 1) * ppp]
+        return jnp.concatenate([t[0, 0].astype(jnp.float32) for t in group], axis=0)
 
     @pl.when(start < length)
     def _compute():
-        def load_pages(ref):
-            # resolve + stream this group's pages; python loop is static
-            # (pages_per_program), each load is one page's (page, d) tile
-            tiles = []
-            for i in range(pages_per_program):
-                pid = pt_ref[b, grp * pages_per_program + i]
-                idx = (pl.dslice(pid, 1), pl.dslice(h, 1), slice(None), slice(None))
-                tiles.append(pl.load(ref, idx)[0, 0])
-            return jnp.concatenate(tiles, axis=0).astype(jnp.float32)
-
         q = q_ref[0, 0].astype(jnp.float32)  # (G, dk)
-        k = load_pages(k_ref)  # (blk, dk)
-        v = load_pages(v_ref)  # (blk, dv)
-        s = jax.lax.dot_general(q, k, _DOT_QK, preferred_element_type=jnp.float32)
+        s = jax.lax.dot_general(q, block(0), _DOT_QK, precision=_HIGHEST,
+                                preferred_element_type=jnp.float32)
         if has_pe:
             qpe = qpe_ref[0, 0].astype(jnp.float32)  # (G, dr)
-            kpe = load_pages(kpe_ref)  # (blk, dr)
-            s = s + jax.lax.dot_general(qpe, kpe, _DOT_QK, preferred_element_type=jnp.float32)
+            s = s + jax.lax.dot_general(qpe, block(1), _DOT_QK, precision=_HIGHEST,
+                                        preferred_element_type=jnp.float32)
         s = s * sm_scale  # (G, blk)
-        pos = start + jax.lax.broadcasted_iota(jnp.int32, (blk,), 0)
-        valid = (pos < length)[None, :]
+        pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        valid = pos < length
         s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
+        m_prev = m_ref[...]  # (G, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(valid, jnp.exp(s - m_new[:, None]), 0.0)
-        l_ref[...] = l_ref[...] * alpha + p.sum(axis=1)
-        pv = jax.lax.dot_general(p, v, _DOT_PV, preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + pv
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
+        pv = jax.lax.dot_general(p, block(n_pools - 1), _DOT_PV, precision=_HIGHEST,
+                                 preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * alpha + pv
         m_ref[...] = m_new
 
     @pl.when(grp == n_groups - 1)
     def _finalize():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def paged_flash_decode_pallas(
@@ -222,60 +216,77 @@ def paged_flash_decode_pallas(
     kpe_pages: Optional[jnp.ndarray] = None,  # (n_pages, Hk, page, dr)
     sm_scale: Optional[float] = None,
     pages_per_program: int = 4,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Paged-native flash decode: the pool is read in place (zero copy).
 
-    Returns (B, Hk, G, dv).  Shares its blocking (``pages_per_program``
-    pages = one score block) and float associativity with the jnp
-    ``stream``/``gather`` implementations in ops.py; interpret mode matches
-    them to float exactness (the last ulp can differ — XLA may pick a
-    different gemm microkernel for the per-program 2D dots than for the
-    batched einsums).
+    Returns (B, Hk, G, dv).  Each pool is passed once per page slot of a
+    group, with a BlockSpec whose index map resolves that slot through the
+    scalar-prefetched page table, so the pipeline DMAs exactly the group's
+    pages into VMEM (and prefetches the next group's while this one
+    computes).  A (page, d) block spans the pool's last two dims whole,
+    which the TPU compiler requires when d is narrower than a 128-lane tile
+    (it refuses a manual DMA of such a slice).  Groups past a row's length
+    re-use the row's last group's block index, so they fetch nothing.
+
+    Shares its blocking (``pages_per_program`` pages = one score block) and
+    float associativity with the jnp ``stream``/``gather`` implementations
+    in ops.py, and like them runs every dot at ``Precision.HIGHEST``; only
+    the accumulation order inside a dot may differ, so outputs agree to
+    float rounding, not bitwise.
     """
     b, hk, g, dk = q.shape
     n_pages, _, page_size, dv = v_pages.shape
     n_pp = page_tables.shape[1]
     has_pe = q_pe is not None
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(dk)
-    pages_per_program = max(1, min(pages_per_program, n_pp))
-    padc = (-n_pp) % pages_per_program
+    ppp = max(1, min(pages_per_program, n_pp))
+    padc = (-n_pp) % ppp
     if padc:  # pad with the scratch page; padded positions are masked out
         page_tables = jnp.pad(page_tables, ((0, 0), (0, padc)))
-    n_groups = page_tables.shape[1] // pages_per_program
+    n_groups = page_tables.shape[1] // ppp
+    blk = ppp * page_size
     kernel = functools.partial(
         _paged_decode_kernel,
         sm_scale=scale,
         page_size=page_size,
-        pages_per_program=pages_per_program,
+        pages_per_program=ppp,
         n_groups=n_groups,
         has_pe=has_pe,
     )
-    dr = 0 if q_pe is None else q_pe.shape[3]
-    q_specs = [pl.BlockSpec((1, 1, g, dk), lambda b_, h_, g_, pt, ln: (b_, h_, 0, 0))]
-    pool_specs = [pl.BlockSpec(memory_space=pltpu.ANY)]
-    if has_pe:
-        q_specs.append(pl.BlockSpec((1, 1, g, dr), lambda b_, h_, g_, pt, ln: (b_, h_, 0, 0)))
-        pool_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+
+    def head_block(width):
+        return pl.BlockSpec((1, 1, g, width), lambda b_, h_, g_, pt, ln: (b_, h_, 0, 0))
+
+    def page_block(width, i):
+        def index_map(b_, h_, g_, pt, ln):
+            last = jnp.maximum(ln[b_] - 1, 0) // blk
+            return (pt[b_, jnp.minimum(g_, last) * ppp + i], h_, 0, 0)
+
+        return pl.BlockSpec((1, 1, page_size, width), index_map)
+
+    pools = [k_pages, kpe_pages, v_pages] if has_pe else [k_pages, v_pages]
+    q_specs = [head_block(dk)] + ([head_block(q_pe.shape[3])] if has_pe else [])
+    page_specs = [page_block(pool.shape[3], i) for pool in pools for i in range(ppp)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, hk, n_groups),
-        in_specs=q_specs + pool_specs + [pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec((1, 1, g, dv), lambda b_, h_, g_, pt, ln: (b_, h_, 0, 0)),
+        in_specs=q_specs + page_specs,
+        out_specs=head_block(dv),
         scratch_shapes=[
             pltpu.VMEM((g, dv), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),
         ],
     )
     operands = [page_tables.astype(jnp.int32), lengths.astype(jnp.int32), q]
-    if has_pe:
-        operands += [q_pe, k_pages, kpe_pages, v_pages]
-    else:
-        operands += [k_pages, v_pages]
+    operands += ([q_pe] if has_pe else []) + [pool for pool in pools for _ in range(ppp)]
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hk, g, dv), q.dtype),
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
+        interpret=pallas_interpret(interpret),
     )(*operands)

@@ -8,10 +8,10 @@ page table.  Three implementations share one blocking scheme
 associativity.  ``stream`` and ``gather`` are **bit-identical** under any
 page table / fill / blocking (tests assert it — this is what lets the
 engine switch between them without perturbing prefix-cache guarantees);
-the Pallas kernel computes the same blocked math and matches them to
-float exactness (interpret mode may lower the per-program 2D dots through
-a different gemm microkernel than the batched einsum, so the last ulp is
-not contractual there):
+the Pallas kernel computes the same blocked math at the same
+``Precision.HIGHEST`` dots and matches them to float exactness (its
+per-program 2D dots may accumulate in another order than the batched
+einsum, so the last ulp is not contractual there):
 
 * ``stream`` — paged-native jnp: a bounded loop gathers only the current
   group's pages ((B, ppp, Hk, page, d)) and runs an online softmax; the
@@ -19,18 +19,21 @@ not contractual there):
   not O(cache capacity).  No (B, Hk, P*page, d) dense KV intermediate ever
   exists in the jaxpr.  This is the engine's CPU path.
 * ``pallas`` — ``paged_flash_decode_pallas``: same algorithm with the page
-  table as a scalar-prefetch operand and pages streamed through VMEM
-  (TPU path; interpret mode is the correctness proxy).
+  table as a scalar-prefetch operand and pages streamed through VMEM.
+  This is the engine's path on a TPU; on the CPU it runs in interpret
+  mode, which the tests use as its correctness proxy.
 * ``gather`` — the legacy fallback and correctness oracle: materializes
   the full (B, Hk, P*page, d) gather, then runs the same blocked online
   softmax over it.  Pays the copy plus O(capacity) compute every step.
 
-``pages_per_program`` defaults to the ``repro.kernels.tune`` config cache
-entry for the call's (shape, dtype, backend) key when one exists.
+``pages_per_program`` defaults to ``DEFAULT_PAGES_PER_PROGRAM``, or to the
+``repro.kernels.tune`` config cache entry for the call's (shape, dtype,
+backend) key when a cache file was named (see ``tune.default_cache``).
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax.numpy as jnp
@@ -55,7 +58,6 @@ def decode_attention_auto(
     lengths: jnp.ndarray,
     *,
     use_pallas: bool = False,
-    interpret: bool = True,
     block_k: int = 512,
     sm_scale: Optional[float] = None,
     tuned: bool = False,
@@ -74,9 +76,7 @@ def decode_attention_auto(
     if g > 1:
         k_cache = jnp.repeat(k_cache, g, axis=1)
         v_cache = jnp.repeat(v_cache, g, axis=1)
-    return flash_decode_pallas(
-        q, k_cache, v_cache, lengths, sm_scale=sm_scale, block_k=block_k, interpret=interpret
-    )
+    return flash_decode_pallas(q, k_cache, v_cache, lengths, sm_scale=sm_scale, block_k=block_k)
 
 
 # ---------------------------------------------------------------------------
@@ -98,9 +98,11 @@ def _block_update(q, qpe, k_blk, kpe_blk, v_blk, start, length, scale, acc, m, l
     ``gather`` (and mirrored inside the Pallas kernel): q (..., G, dk),
     blocks (..., blk, d*), running stats acc (..., G, dv) / m, l (..., G)."""
     blk = k_blk.shape[-2]
-    s = jnp.einsum("...gd,...pd->...gp", q, k_blk, preferred_element_type=jnp.float32)
+    dot = functools.partial(jnp.einsum, precision=lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
+    s = dot("...gd,...pd->...gp", q, k_blk)
     if qpe is not None:
-        s = s + jnp.einsum("...gd,...pd->...gp", qpe, kpe_blk, preferred_element_type=jnp.float32)
+        s = s + dot("...gd,...pd->...gp", qpe, kpe_blk)
     s = s * scale
     pos = start + lax.broadcasted_iota(jnp.int32, (blk,), 0)
     valid = pos[None, :] < length[:, None]  # (B, blk)
@@ -110,7 +112,7 @@ def _block_update(q, qpe, k_blk, kpe_blk, v_blk, start, length, scale, acc, m, l
     alpha = jnp.exp(m - m_new)
     p = jnp.where(valid, jnp.exp(s - m_new[..., None]), 0.0)
     l_new = l * alpha + p.sum(axis=-1)
-    pv = jnp.einsum("...gp,...pd->...gd", p, v_blk, preferred_element_type=jnp.float32)
+    pv = dot("...gp,...pd->...gd", p, v_blk)
     acc_new = acc * alpha[..., None] + pv
     return acc_new, m_new, l_new
 
@@ -198,9 +200,7 @@ def _gather_core(q, qpe, k_pages, kpe_pages, v_pages, lengths, page_tables, scal
     return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
 
 
-def _paged_dispatch(
-    q, qpe, k_pages, kpe_pages, v_pages, lengths, page_tables, scale, impl, ppp, interpret
-):
+def _paged_dispatch(q, qpe, k_pages, kpe_pages, v_pages, lengths, page_tables, scale, impl, ppp):
     n_pp = page_tables.shape[1]
     page_tables, ppp, n_groups = _paged_prep(q, page_tables, ppp, n_pp)
     args = (q, qpe, k_pages, kpe_pages, v_pages, lengths, page_tables, scale, ppp, n_groups)
@@ -219,7 +219,6 @@ def _paged_dispatch(
             kpe_pages=kpe_pages,
             sm_scale=scale,
             pages_per_program=ppp,
-            interpret=interpret,
         )
     raise ValueError(f"impl={impl!r} not in {PAGED_IMPLS}")
 
@@ -234,7 +233,6 @@ def paged_decode_attention(
     sm_scale: Optional[float] = None,
     impl: str = "stream",
     pages_per_program: Optional[int] = None,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """GQA decode attention over the paged KV pool; returns (B, Hq, d).
 
@@ -264,7 +262,6 @@ def paged_decode_attention(
         scale,
         impl,
         pages_per_program,
-        interpret,
     )
     return out.reshape(b, hq, d)
 
@@ -354,7 +351,6 @@ def paged_verify_attention(
     sm_scale: Optional[float] = None,
     impl: str = "stream",
     pages_per_program: Optional[int] = None,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """Multi-query verify over pages: decode attention for T draft positions
     per sequence in one call, by folding the window into the batch axis with
@@ -368,8 +364,7 @@ def paged_verify_attention(
     pts = jnp.repeat(page_tables, t, axis=0)
     out = paged_decode_attention(
         q.reshape(b * t, hq, d), k_pages, v_pages, lens, pts,
-        sm_scale=sm_scale, impl=impl, pages_per_program=pages_per_program,
-        interpret=interpret)
+        sm_scale=sm_scale, impl=impl, pages_per_program=pages_per_program)
     return out.reshape(b, t, hq, d)
 
 
@@ -384,7 +379,6 @@ def paged_latent_decode_attention(
     sm_scale: float,
     impl: str = "stream",
     pages_per_program: Optional[int] = None,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """MLA latent decode over paged (c_kv, k_pe) pools; returns latent
     context (B, H, r).  scores = q_lat*ckv + q_pe*kpe; context accumulates
@@ -410,6 +404,5 @@ def paged_latent_decode_attention(
         sm_scale,
         impl,
         pages_per_program,
-        interpret,
     )
     return out[:, 0]

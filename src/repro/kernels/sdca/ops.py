@@ -7,11 +7,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.sdca.kernel import local_sdca_pallas
+from repro.kernels.sdca.kernel import VMEM_CAPACITY, local_sdca_pallas, vmem_bytes
 from repro.kernels.sdca.ref import local_sdca_ref
-
-# VMEM budget (bytes) for the per-worker shard tile on v5e (~16 MiB usable)
-VMEM_BUDGET = 12 * 1024 * 1024
 
 
 def local_sdca(
@@ -25,7 +22,6 @@ def local_sdca(
     n: float,
     *,
     use_pallas: bool = False,
-    interpret: bool = True,
     tuned: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     m, nl, d = X.shape
@@ -34,9 +30,14 @@ def local_sdca(
 
         shape = {"m": m, "nl": nl, "d": d, "h": idx.shape[1]}
         use_pallas = bool(_tuned_value("sdca", shape, X.dtype, "use_pallas", int(use_pallas)))
-    fits_vmem = (nl * d + 2 * nl + 2 * d) * 4 <= VMEM_BUDGET
-    if use_pallas and fits_vmem:
-        return local_sdca_pallas(X, y, a, w, idx, sigma_prime, lam, n, interpret=interpret)
+    if use_pallas:
+        need = vmem_bytes(nl, d)
+        if need > VMEM_CAPACITY:
+            raise ValueError(
+                f"SDCA kernel asked for a ({nl}, {d}) shard whose blocks need "
+                f"{need} bytes of VMEM > {VMEM_CAPACITY}; use more workers"
+            )
+        return local_sdca_pallas(X, y, a, w, idx, sigma_prime, lam, n)
 
     def one_worker(Xk, yk, ak, ik):
         return local_sdca_ref(Xk, yk, ak, w, ik, sigma_prime, lam, n)

@@ -12,13 +12,14 @@ O(S * Dn * N) a naive materialized scan would move.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
+from repro.kernels import pallas_interpret
 
 
 def _ssm_kernel(
@@ -72,7 +73,7 @@ def selective_scan_pallas(
     *,
     chunk: int = 128,
     d_block: int = 256,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     bt, s, dn = x.shape
     n = A.shape[1]
@@ -104,9 +105,9 @@ def selective_scan_pallas(
         out_specs=pl.BlockSpec((1, chunk, d_block), lambda b, di, ci: (b, ci, di)),
         out_shape=jax.ShapeDtypeStruct((bt, nc * chunk, nd * d_block), x.dtype),
         scratch_shapes=[pltpu.VMEM((d_block, n), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(x_, dt_, A_, B_, C_, D_)
     return y[:, :s, :dn]

@@ -12,8 +12,8 @@ Public surface:
 * ``lookup(family, shape, dtype)`` — cheap read-only cache hit for the
   ``tuned=True`` paths in the ops wrappers; never sweeps, returns None on
   a miss (callers fall back to their defaults).  Safe under jit tracing.
-* ``default_cache()`` — process-wide cache bound to
-  ``$REPRO_TUNE_CACHE`` / ``results/tune_cache.json``.
+* ``default_cache()`` — process-wide cache bound to ``$REPRO_TUNE_CACHE``
+  when set, else in memory (untracked files never steer a compile).
 * ``tune_events`` / ``bench_rows`` — telemetry export: typed bus events
   for ``CapacityPlanner.ingest``/dryrun system-model fitting, bench rows
   for the perf-gate trajectory (``decode_step_rows`` is the deprecated

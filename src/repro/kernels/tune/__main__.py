@@ -20,6 +20,7 @@ from repro.kernels.tune import (
     bench_rows,
     sweep_all,
 )
+from repro.kernels.tune.cache import DEFAULT_CACHE_PATH
 
 
 def main() -> None:
@@ -28,7 +29,7 @@ def main() -> None:
     ap.add_argument("--families", nargs="+", default=list(FAMILIES), choices=list(FAMILIES))
     ap.add_argument(
         "--cache",
-        default=ConfigCache.default_path(),
+        default=ConfigCache.default_path() or DEFAULT_CACHE_PATH,
         help="config-cache JSON path (default: $REPRO_TUNE_CACHE or results/tune_cache.json)",
     )
     ap.add_argument("--dtype", default="float32", choices=["float32", "bfloat16"])

@@ -11,9 +11,11 @@ order, jnp dtype name, and ``jax.default_backend()``.  The value side
 keeps the original shape dict so consumers (telemetry export, capacity
 planning) never parse the signature back.
 
-Persistence is a single JSON file (default ``results/tune_cache.json``,
-overridable via ``$REPRO_TUNE_CACHE`` or the ``path`` argument), written
+Persistence is a single JSON file (the ``path`` argument; the sweep CLI
+writes ``results/tune_cache.json`` unless told otherwise), written
 atomically (tmp + rename).  ``path=None`` keeps the cache in memory only.
+Kernel-geometry lookups read a file only when ``$REPRO_TUNE_CACHE`` names
+one, so what the program compiles follows from tracked code by default.
 """
 
 from __future__ import annotations
@@ -61,8 +63,10 @@ class ConfigCache:
             self.load()
 
     @classmethod
-    def default_path(cls) -> str:
-        return os.environ.get("REPRO_TUNE_CACHE", DEFAULT_CACHE_PATH)
+    def default_path(cls) -> Optional[str]:
+        """The file kernel-geometry lookups read: ``$REPRO_TUNE_CACHE``, or
+        none (in memory, so every kernel takes its tracked default)."""
+        return os.environ.get("REPRO_TUNE_CACHE")
 
     # ------------------------------------------------------------------
     def get(self, key: str) -> Optional[Dict]:

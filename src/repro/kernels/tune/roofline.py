@@ -21,10 +21,12 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Sequence, Tuple
 
+from repro.kernels.sdca.kernel import vmem_bytes as sdca_vmem_bytes
+
 # TPU v5e roofline constants — keep in sync with repro/launch/dryrun.py
 PEAK_FLOPS = 197e12  # bf16 FLOP/s per chip
 HBM_BW = 819e9  # bytes/s per chip
-VMEM_BUDGET = 12 * 1024 * 1024  # usable VMEM bytes (matches sdca/ops.py)
+VMEM_BUDGET = 12 * 1024 * 1024  # VMEM a pruned-in candidate's tiles may take
 GRID_STEP_OVERHEAD_S = 1e-6  # per-program dispatch floor
 PRUNE_SLACK = 3.0
 
@@ -111,7 +113,7 @@ def estimate(family: str, shape: Dict[str, int], config: Dict[str, int]) -> Cand
         flops = 4.0 * m * h * d
         bytes_moved = m * (nl * d + 2 * nl + 2 * d) * it
         # the pallas variant keeps the whole shard tile resident
-        vmem = (nl * d + 2 * nl + 2 * d) * it if config.get("use_pallas") else 0
+        vmem = sdca_vmem_bytes(nl, d) if config.get("use_pallas") else 0
         steps = m
     else:
         raise ValueError(f"unknown kernel family {family!r}")

@@ -132,10 +132,9 @@ def _case_flash_decode(shape, dtype):
     kc = jax.random.normal(ks[1], (b, h, s, d), dtype)
     vc = jax.random.normal(ks[2], (b, h, s, d), dtype)
     lens = jnp.asarray(ragged_lengths(b, s))
-    interpret = jax.default_backend() != "tpu"
 
     def build(config):
-        fn = jax.jit(functools.partial(flash_decode_pallas, interpret=interpret, **config))
+        fn = jax.jit(functools.partial(flash_decode_pallas, **config))
         return fn, (q, kc, vc, lens)
 
     return build

@@ -9,9 +9,22 @@ so these shapes can be built from placeholder host devices.
 """
 from __future__ import annotations
 
+from typing import Optional, Sequence
+
 import numpy as np
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes, devices):
+    """Mesh with ``Auto`` axes: the partitioner propagates shardings from
+    the placed inputs and ``with_sharding_constraint`` hints, which is what
+    every rule in ``dist/partitioning.py`` assumes.  (``jax.make_mesh``
+    defaults to ``Explicit`` axes since JAX 0.7, under which an unannotated
+    gather such as the embedding lookup is a sharding type error.)"""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -24,17 +37,19 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for mesh {shape}, have {len(devices)}; "
             "set XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "importing jax (launch/dryrun.py does this)")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return _mesh(shape, axes, devices[:n])
 
 
-def make_debug_mesh(data: int = 1, model: int = 1):
-    """Small mesh over however many (possibly forced-host) devices exist."""
+def make_debug_mesh(data: int = 1, model: int = 1,
+                    devices: Optional[Sequence] = None):
+    """Small mesh over however many (possibly forced-host) devices exist:
+    the first ``data * model``, or exactly ``devices`` when given (a
+    one-device mesh pins a serving replica to its own chip)."""
     n = data * model
-    devices = jax.devices()
-    if len(devices) < n:
+    devices = list(jax.devices()[:n] if devices is None else devices)
+    if len(devices) != n:
         raise RuntimeError(f"need {n} devices, have {len(devices)}")
-    return jax.make_mesh((data, model), ("data", "model"),
-                         devices=devices[:n])
+    return _mesh((data, model), ("data", "model"), devices)
 
 
 def make_scaled_mesh(n_chips: int, model: int = 16):
@@ -47,5 +62,4 @@ def make_scaled_mesh(n_chips: int, model: int = 16):
     devices = jax.devices()
     if len(devices) < data * model:
         raise RuntimeError(f"need {data * model} devices, have {len(devices)}")
-    return jax.make_mesh((data, model), ("data", "model"),
-                         devices=devices[: data * model])
+    return _mesh((data, model), ("data", "model"), devices[: data * model])

@@ -48,6 +48,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import CapacityPlanner, Router, ServeEngine
 
 
@@ -65,8 +66,8 @@ class Server:
         self.page_size = page_size
         self.rt = None
         if mesh is not None or rules is not None:
-            self.rt = _serving_runtime(page_size, "stream", mesh=mesh,
-                                       rules=rules)
+            self.rt = ServeEngine.default_runtime(page_size, mesh=mesh,
+                                                  rules=rules)
         self._engine: Optional[ServeEngine] = None
         self.cfg = ServeEngine.config_for(arch, smoke)
 
@@ -106,17 +107,6 @@ class Server:
         }
 
 
-def _serving_runtime(page_size: int, paged_impl: str, *, mesh=None,
-                     rules=None):
-    """Serving Runtime with the engine's pinned kernel geometry (see
-    ServeEngine.__init__ on why block_q = block_k = 16)."""
-    from repro.models.runtime import Runtime
-
-    return Runtime(remat="none", block_q=16, block_k=16, scan_chunk=32,
-                   page_size=page_size, paged_impl=paged_impl, mesh=mesh,
-                   rules=rules)
-
-
 # One trace request: (prompt, gen_tokens, arrival_step, frontend_embeds).
 TraceSpec = Tuple[np.ndarray, int, int, Optional[np.ndarray]]
 
@@ -149,32 +139,58 @@ def _mixed_trace_specs(cfg, page_size: int, n_requests: int,
     return specs
 
 
-def _mixed_trace(eng: ServeEngine, n_requests: int, seed: int):
-    specs = _mixed_trace_specs(eng.cfg, eng.page_size, n_requests, seed)
+def _submit_specs(eng: ServeEngine, specs: List[TraceSpec]):
     return [eng.submit(prompt, gen, arrival_step=arrival, frontend_embeds=fe)
             for prompt, gen, arrival, fe in specs]
 
 
-def _verify_prefix_reuse(arch: str, smoke: bool, eng: ServeEngine,
-                         seed: int) -> bool:
-    """Serve one prefix-sharing prompt on the warm engine and the same
-    prompt cold; logits must match bit-for-bit."""
+# generation length of each prefix-reuse probe request
+_PROBE_GEN = 4
+
+
+def _prefix_probe_prompts(cfg, page_size: int,
+                          seed: int) -> Tuple[np.ndarray, np.ndarray]:
+    """Two prompts sharing a two-page head, for ``_verify_prefix_reuse``."""
     rng = np.random.RandomState(seed + 1)
-    ps = eng.page_size
-    head = rng.randint(0, eng.cfg.vocab_size, 2 * ps).astype(np.int32)
-    pA = np.concatenate([head, rng.randint(0, eng.cfg.vocab_size, 5)
+    head = rng.randint(0, cfg.vocab_size, 2 * page_size).astype(np.int32)
+    pA = np.concatenate([head, rng.randint(0, cfg.vocab_size, 5)
                          .astype(np.int32)])
-    pB = np.concatenate([head, rng.randint(0, eng.cfg.vocab_size, 9)
+    pB = np.concatenate([head, rng.randint(0, cfg.vocab_size, 9)
                          .astype(np.int32)])
+    return pA, pB
+
+
+def _trace_max_seq(cfg, specs: List[TraceSpec], probes, page_size: int) -> int:
+    """Cache positions the longest request served needs (trace requests
+    and prefix-reuse probes), rounded up to whole pages: the engines'
+    ``max_seq``, from which their page tables and pools are sized."""
+    need = [len(prompt) + gen + (0 if fe is None else cfg.n_frontend_tokens)
+            for prompt, gen, _, fe in specs]
+    need += [len(p) + _PROBE_GEN for p in probes]
+    return -(-max(need) // page_size) * page_size
+
+
+def _verify_prefix_reuse(arch: str, smoke: bool, eng: ServeEngine,
+                         probes) -> bool:
+    """Serve one prefix-sharing prompt on the warm engine and the same
+    prompt cold (a fresh engine over the same weights, configured alike);
+    logits must match bit-for-bit.  The cold engine prefills and decodes
+    through the same programs as the warm one (chunked or not, speculative
+    or not), so the check isolates the reuse of shared pages: on a TPU a
+    chunked and a monolithic prefill are different XLA programs, whose
+    rounding is not bitwise alike."""
+    pA, pB = probes
     eng.collect_logits = True
-    eng.submit(pA, 4)
+    eng.submit(pA, _PROBE_GEN)
     eng.run()
-    rB = eng.submit(pB, 4)
+    rB = eng.submit(pB, _PROBE_GEN)
     eng.run()
-    cold = ServeEngine(arch, smoke=smoke, max_batch=eng.max_batch,
-                       page_size=ps, max_seq=eng.max_seq, seed=eng.seed,
-                       collect_logits=True)
-    rB_cold = cold.submit(pB, 4)
+    cold = ServeEngine(arch, smoke=smoke,
+                       max_batch=eng.max_batch, page_size=eng.page_size,
+                       max_seq=eng.max_seq, seed=eng.seed, params=eng.params,
+                       rt=eng.rt, prefill_chunk=eng.prefill_chunk,
+                       speculate=eng.speculate, collect_logits=True)
+    rB_cold = cold.submit(pB, _PROBE_GEN)
     cold.run()
     shared = rB.n_shared_pages
     exact = all(np.array_equal(a, b)
@@ -263,37 +279,50 @@ def _export_trace(args, events, planner, busy_s: float, n_layers: int) -> None:
             sys.exit(1)
 
 
-def _run_router(args, specs: List[TraceSpec], reference, n_replicas: int,
-                prefill_chunk: Optional[int]) -> "Router":
+def _run_router(args, eng: ServeEngine, specs: List[TraceSpec], reference,
+                n_replicas: int, prefill_chunk: Optional[int]) -> "Router":
     """Replay the reference trace through a prefix-affinity router over
-    ``n_replicas`` engines and assert bit-identical per-request outputs."""
+    ``n_replicas`` engines and assert bit-identical per-request outputs.
+
+    Every replica serves ``eng``'s weights.  With ``--tp K`` each runs on
+    one K-device mesh; otherwise, where several devices exist, replica i is
+    pinned to device i (mod the device count) by a one-device mesh."""
+    import jax
+
+    from repro.launch.mesh import make_debug_mesh
+
+    devices = jax.devices()
     mesh = None
     if args.tp > 1:
-        from repro.launch.mesh import make_debug_mesh
-
         mesh = make_debug_mesh(1, args.tp)
         print(f"tensor parallel: {args.tp}-way over mesh "
               f"{dict(zip(mesh.axis_names, mesh.devices.shape))}")
-    rt = _serving_runtime(args.page_size, args.paged_impl, mesh=mesh)
+
+    def placement(i: int):
+        if mesh is not None or len(devices) == 1:
+            return mesh
+        return make_debug_mesh(1, 1, devices=[devices[max(i, 0) % len(devices)]])
 
     clock = _trace_clock_factory(args)
 
     def make_engine(i: int) -> ServeEngine:
+        rt = ServeEngine.default_runtime(args.page_size,
+                                         paged_impl=args.paged_impl,
+                                         mesh=placement(i))
         return ServeEngine(
             args.arch, smoke=args.smoke, max_batch=args.max_batch,
-            page_size=args.page_size, max_seq=64 + args.page_size * 2,
-            seed=args.seed, rt=rt, prefill_chunk=prefill_chunk,
-            speculate=args.speculate, replica_id=i,
-            trace=bool(args.trace), trace_clock=clock())
+            page_size=args.page_size, max_seq=eng.max_seq,
+            seed=args.seed, params=eng.params, rt=rt,
+            prefill_chunk=prefill_chunk, speculate=args.speculate,
+            replica_id=i, trace=bool(args.trace), trace_clock=clock())
 
-    if mesh is not None:
+    if args.tp > 1:
         # bit-identity is a same-placement guarantee: TP psums reduce in a
         # different order than the unsharded engine, so at K > 1 the routed
         # fleet is compared against a single engine on the SAME mesh (the
         # unsharded reference agrees to float tolerance, not bitwise)
         ref = make_engine(-1)
-        for prompt, gen, arrival, fe in specs:
-            ref.submit(prompt, gen, arrival_step=arrival, frontend_embeds=fe)
+        _submit_specs(ref, specs)
         ref.run()
         reference = ref.scheduler.finished
         reference.sort(key=lambda r: r.rid)
@@ -384,11 +413,12 @@ def main():
                          "sequence per step from an n-gram/prefix-cache "
                          "proposer, verified in one batched target step "
                          "(default 0 = off)")
-    ap.add_argument("--paged-impl", default="stream",
+    ap.add_argument("--paged-impl", default=None,
                     choices=["stream", "pallas", "gather"],
-                    help="paged decode implementation (bit-identical; "
-                         "stream is paged-native, gather is the legacy "
-                         "oracle)")
+                    help="paged decode implementation (default: the Pallas "
+                         "kernel on a TPU with one device per replica, "
+                         "stream otherwise; stream and gather are "
+                         "bit-identical, gather is the legacy oracle)")
     ap.add_argument("--tune-cache", default=None, metavar="PATH",
                     help="seed the capacity planner with measured "
                          "paged-decode kernel timings from this autotuner "
@@ -431,6 +461,7 @@ def main():
                     help="tensor-parallel world size per replica (forces K "
                          "host devices; must be first jax initialization)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.migrate_at is not None:
         args.router = True
     if args.router or args.trace:
@@ -454,17 +485,20 @@ def main():
         return
 
     prefill_chunk = _resolve_prefill_chunk(args.prefill_chunk, args.smoke)
+    cfg = ServeEngine.config_for(args.arch, args.smoke)
+    specs = _mixed_trace_specs(cfg, args.page_size, args.requests, args.seed)
+    probes = _prefix_probe_prompts(cfg, args.page_size, args.seed)
     eng = ServeEngine(args.arch, smoke=args.smoke, max_batch=args.max_batch,
                       page_size=args.page_size,
-                      max_seq=64 + args.page_size * 2, seed=args.seed,
-                      paged_impl=args.paged_impl,
+                      max_seq=_trace_max_seq(cfg, specs, probes,
+                                             args.page_size),
+                      seed=args.seed, paged_impl=args.paged_impl,
                       prefill_chunk=prefill_chunk, speculate=args.speculate,
                       trace=bool(args.trace),
                       trace_clock=_trace_clock_factory(args)())
-    specs = _mixed_trace_specs(eng.cfg, eng.page_size, args.requests,
-                               args.seed)
-    reqs = [eng.submit(prompt, gen, arrival_step=arrival, frontend_embeds=fe)
-            for prompt, gen, arrival, fe in specs]
+    print(f"engine: {eng.cfg.name} max_seq={eng.max_seq} "
+          f"pages={eng.pool.num_pages} paged_impl={eng.rt.paged_impl}")
+    reqs = _submit_specs(eng, specs)
     stats = eng.run()
     done = [r for r in reqs if r.finished_step >= 0]
     print(f"served {len(done)}/{len(reqs)} requests in {eng.step_count} steps "
@@ -489,10 +523,9 @@ def main():
                   f"{stats.get('draft_proposed', 0)} drafted tokens)")
         base = ServeEngine(args.arch, smoke=args.smoke,
                            max_batch=args.max_batch,
-                           page_size=args.page_size,
-                           max_seq=64 + args.page_size * 2, seed=args.seed,
-                           paged_impl=args.paged_impl)
-        base_reqs = _mixed_trace(base, args.requests, args.seed)
+                           page_size=args.page_size, max_seq=eng.max_seq,
+                           seed=args.seed, params=eng.params, rt=eng.rt)
+        base_reqs = _submit_specs(base, specs)
         base.run()
         identical = all(r.generated == b.generated
                         for r, b in zip(reqs, base_reqs))
@@ -539,7 +572,8 @@ def main():
             n_replicas = plan.m if plan else 2
             print(f"router: --replicas 0 -> planner min-replicas answer "
                   f"m={n_replicas}")
-        router = _run_router(args, specs, reqs, n_replicas, prefill_chunk)
+        router = _run_router(args, eng, specs, reqs, n_replicas,
+                             prefill_chunk)
 
     if args.trace:
         trace_events = (router.all_events() if router is not None
@@ -549,7 +583,7 @@ def main():
         _export_trace(args, list(trace_events) + tune_evs, planner, busy,
                       eng.cfg.n_layers)
 
-    ok = _verify_prefix_reuse(args.arch, args.smoke, eng, args.seed)
+    ok = _verify_prefix_reuse(args.arch, args.smoke, eng, probes)
     if not ok:
         print("FAIL: prefix-reuse verification")
         sys.exit(1)

@@ -24,6 +24,7 @@ from repro.compression.gradient import CompressionConfig, GradientCompressor
 from repro.configs import get_config, get_smoke_config
 from repro.data.pipeline import SyntheticTokens
 from repro.dist.partitioning import Rules
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import LM
 from repro.models.runtime import Runtime
 from repro.runtime.failures import FailureInjector, RestartPolicy, SimulatedFailure
@@ -322,6 +323,7 @@ def main():
     ap.add_argument("--chaos-out", default=None,
                     help="write the replayable run log JSON here")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.chaos is not None:
         import tempfile
         from pathlib import Path

@@ -136,7 +136,6 @@ def apply_attention_decode_paged(
     page_size: int,
     paged_impl: str = "stream",
     pages_per_program: Optional[int] = None,
-    interpret: bool = True,
 ) -> Tuple[jnp.ndarray, Dict]:
     """Paged-KV decode: scatter the new token's K/V into its page, then run
     decode attention against the page pool in place.  ``paged_impl`` picks
@@ -157,8 +156,7 @@ def apply_attention_decode_paged(
         v_new.astype(cache["v"].dtype))
     out = paged_decode_attention(
         q[:, 0], k_pages, v_pages, lengths + 1, page_tables,
-        impl=paged_impl, pages_per_program=pages_per_program,
-        interpret=interpret)  # (B, H, hd)
+        impl=paged_impl, pages_per_program=pages_per_program)  # (B, H, hd)
     y = out.reshape(b, cfg.n_heads * cfg.head_dim) @ cast_to(
         p["wo"], cfg.dtype)
     return y[:, None, :], {"k": k_pages, "v": v_pages}

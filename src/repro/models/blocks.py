@@ -115,14 +115,12 @@ def apply_block_decode_paged(
                 p["mixer"], h, cfg, cache, lengths, page_tables,
                 page_size=rt.page_size, absorb=rt.mla_absorb,
                 paged_impl=rt.paged_impl,
-                pages_per_program=rt.pages_per_program,
-                interpret=rt.interpret)
+                pages_per_program=rt.pages_per_program)
         else:
             y, new_cache = attn_mod.apply_attention_decode_paged(
                 p["mixer"], h, cfg, cache, lengths, page_tables,
                 page_size=rt.page_size, paged_impl=rt.paged_impl,
-                pages_per_program=rt.pages_per_program,
-                interpret=rt.interpret)
+                pages_per_program=rt.pages_per_program)
     else:
         y, new_cache = mamba_mod.apply_mamba_decode(
             p["mixer"], h, cfg, cache, constrain_fn=rt.constrain_fn)

@@ -190,7 +190,6 @@ def apply_mla_decode_paged(
     chunk: int = 2048,
     paged_impl: str = "stream",
     pages_per_program: Optional[int] = None,
-    interpret: bool = True,
 ) -> Tuple[jnp.ndarray, Dict]:
     """Paged latent-cache decode: scatter the new (c_kv, k_pe) into its page,
     then attend over the latent pool in place (absorbed form: W_uk folded
@@ -230,7 +229,7 @@ def apply_mla_decode_paged(
     ctx_lat = paged_latent_decode_attention(
         q_lat, q_pe[:, 0], ckv_pages, kpe_pages, lengths + 1, page_tables,
         sm_scale=scale, impl=paged_impl,
-        pages_per_program=pages_per_program, interpret=interpret)
+        pages_per_program=pages_per_program)
     out = jnp.einsum("bhr,rhe->bhe", ctx_lat.astype(dt), wv)  # (B, H, v)
     y = out.reshape(b, h * m.v_head_dim) @ cast_to(p["wo"], dt)
     return y[:, None, :], new_cache

@@ -65,19 +65,28 @@ class LM:
                 blocks_mod.init_block(hkeys[i], cfg, head_spec)
                 for i in range(cfg.first_k_dense))
         pkeys = jax.random.split(keys[4], max(cfg.n_periods, 1))
-        per_period = []
-        for pi in range(cfg.n_periods):
-            lkeys = jax.random.split(pkeys[pi], len(cfg.period))
-            per_period.append({
+
+        def init_period(pkey):
+            lkeys = jax.random.split(pkey, len(cfg.period))
+            return {
                 f"pos{i}": blocks_mod.init_block(lkeys[i], cfg, spec)
                 for i, spec in enumerate(cfg.period)
-            })
-        tree["periods"] = stack_periods(per_period)
+            }
+
+        # vmap builds the periods already stacked on the leading axis
+        tree["periods"] = stack_periods(
+            jax.vmap(init_period)(pkeys[:cfg.n_periods]))
         return tree
 
-    def init(self, key: jax.Array):
-        """Returns (param values pytree, logical axes pytree)."""
-        return split_tree(self.init_annotated(key))
+    def init(self, key: jax.Array, out_shardings=None):
+        """Returns (param values pytree, logical axes pytree).
+
+        The values come out of one jitted program, placed per
+        ``out_shardings`` when given, so a full-width model is built where
+        it will live and never holds its layer stack twice."""
+        values = jax.jit(lambda k: split_tree(self.init_annotated(k))[0],
+                         out_shardings=out_shardings)(key)
+        return values, self.param_axes()
 
     def param_axes(self):
         """Axes tree without allocating parameters (eval_shape)."""
@@ -296,12 +305,13 @@ class LM:
         ``repro.serve.cache.init_paged_cache``) and recurrent-state leaves by
         slot.  ``page_tables`` (B, pages_per_seq) int32 maps each sequence's
         logical pages to physical pages; page 0 is the scratch page that idle
-        slots write into.  Attention over the pool is paged-native by
-        default (``Runtime.paged_impl``: "stream" jnp / "pallas" TPU kernel,
-        with the legacy "gather" oracle bit-identical to stream — see
-        kernels/flash_decode/ops.py); ``Runtime.pages_per_program`` defaults
-        to the ``repro.kernels.tune`` config cache.  Returns
-        (logits (B,V), new_cache)."""
+        slots write into.  Attention over the pool runs the implementation
+        ``Runtime.paged_impl`` names ("stream" paged-native jnp / "pallas"
+        TPU kernel / the "gather" oracle bit-identical to stream — see
+        kernels/flash_decode/ops.py; ``ServeEngine`` fills it in from the
+        backend and mesh); ``Runtime.pages_per_program`` defaults to
+        ``DEFAULT_PAGES_PER_PROGRAM`` there.  Returns (logits (B,V),
+        new_cache)."""
         cfg, rt = self.cfg, self.rt
         x = embed_tokens(params["embed"], tokens[:, None], cfg.dtype)
         new_head = []

@@ -33,21 +33,6 @@ from repro.models.layers import cast_to
 from repro.models.param import ann
 
 
-def _shard_map(fn, mesh, in_specs, out_specs):
-    """jax.shard_map with replication checking off, across jax versions
-    (top-level ``jax.shard_map``/``check_vma`` vs the older
-    ``jax.experimental.shard_map``/``check_rep``)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    except TypeError:  # transition releases kept the check_rep kwarg
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-
-
 def init_moe(key: jax.Array, cfg: ArchConfig) -> Dict:
     moe = cfg.moe
     d, e, f = cfg.d_model, moe.n_routed_experts, moe.expert_d_ff
@@ -298,6 +283,6 @@ def apply_moe(
     if has_shared:
         in_specs += [P(None, model_axis), P(None, model_axis), P(model_axis, None)]
         args += [p["sh_gate"], p["sh_up"], p["sh_down"]]
-    y = _shard_map(fn, mesh, tuple(in_specs),
-                   P(bspec, None, None))(*args)
+    y = jax.shard_map(fn, mesh=mesh, in_specs=tuple(in_specs),
+                      out_specs=P(bspec, None, None), check_vma=False)(*args)
     return y, aux

@@ -63,13 +63,9 @@ def split_tree(tree):
     return tree, tuple(None for _ in range(getattr(tree, "ndim", 0)))
 
 
-def stack_periods(trees):
-    """Stack a list of per-period annotated trees along a new leading 'layers'
-    axis (for scan-over-periods)."""
-    import jax.numpy as jnp
-
-    def _stack(*leaves):
-        vals = jnp.stack([l.value for l in leaves])
-        return Annotated(vals, ("layers",) + tuple(leaves[0].axes))
-
-    return jax.tree.map(_stack, *trees, is_leaf=is_annotated)
+def stack_periods(tree):
+    """Name the leading axis of a per-period tree whose values were built
+    stacked (``jax.vmap`` over the period keys) as 'layers' (for
+    scan-over-periods)."""
+    return jax.tree.map(lambda leaf: Annotated(leaf.value, ("layers",) + leaf.axes),
+                        tree, is_leaf=is_annotated)

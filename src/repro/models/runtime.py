@@ -23,15 +23,14 @@ class Runtime:
     scan_chunk: int = 128
     mla_absorb: bool = False
     remat: str = "full"                     # none | full | dots
-    use_pallas: bool = False                # TPU-only kernel path
     page_size: int = 16                     # paged-KV page length (serving)
-    # paged decode implementation: "stream" (paged-native jnp, CPU default),
-    # "pallas" (TPU kernel; interpret mode on CPU), "gather" (legacy dense
-    # gather — the correctness oracle).  All three are bit-identical for the
-    # same pages_per_program (see kernels/flash_decode/ops.py).
-    paged_impl: str = "stream"
-    pages_per_program: Optional[int] = None  # None -> autotuner cache/default
-    interpret: bool = True                   # Pallas interpret mode (no TPU)
+    # paged decode implementation: "stream" (paged-native jnp), "pallas"
+    # (the TPU kernel), "gather" (dense gather — the correctness oracle);
+    # None lets ServeEngine pick from the backend and the mesh.  stream and
+    # gather are bit-identical for the same pages_per_program, pallas agrees
+    # to float rounding (see kernels/flash_decode/ops.py).
+    paged_impl: Optional[str] = None
+    pages_per_program: Optional[int] = None  # None -> default / named tune cache
 
     def constrain(self, x: jax.Array, axes) -> jax.Array:
         return constrain(x, self.rules, axes)

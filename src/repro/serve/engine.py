@@ -37,6 +37,9 @@ the dispatch size and the guarantee extends to folded verify batches.
 
 from __future__ import annotations
 
+import dataclasses
+import functools
+import logging
 import time
 from contextlib import nullcontext
 from typing import Callable, Dict, List, Optional
@@ -58,7 +61,7 @@ from repro.serve.cache import (
 from repro.serve.paging import SCRATCH_PAGE, PagePool
 from repro.serve.prefix import PrefixCache
 from repro.serve.scheduler import Request, RequestState, Scheduler
-from repro.serve.sharding import ShardingPlan
+from repro.serve.sharding import ShardingPlan, default_paged_impl, mesh_world_size
 from repro.serve.speculate import NgramProposer
 from repro.telemetry import (
     Event,
@@ -69,8 +72,18 @@ from repro.telemetry import (
 )
 from repro.telemetry.trace import SpanTracer
 
+logger = logging.getLogger(__name__)
+
 
 class ServeEngine:
+    """Continuous-batching engine for one model replica.
+
+    ``params`` reuses weights another engine already holds (placed onto
+    this engine's devices when its Runtime carries a mesh); by default
+    they are built from ``seed``.  ``paged_impl`` forces a decode-attention
+    implementation; by default it is the Pallas kernel on a TPU whose state
+    sits on one device, and the paged-native jnp path otherwise."""
+
     def __init__(
         self,
         arch: str,
@@ -81,6 +94,7 @@ class ServeEngine:
         max_seq: int = 256,
         num_pages: Optional[int] = None,
         seed: int = 0,
+        params: Optional[Dict] = None,
         prefix_caching: bool = True,
         collect_logits: bool = False,
         rt: Optional[Runtime] = None,
@@ -104,33 +118,37 @@ class ServeEngine:
                 "whose slot-major cache has no paged/positional form"
             )
         self.seed = seed
-        # block_q = block_k = 16 pins the flash-attention blocking: the
-        # kernel clamps blocks to min(block, max(seq, 16)), so 16 is the one
-        # setting whose block grid never depends on prompt length.  That
-        # makes prefix-position activations — and therefore shared prefix
-        # pages — bitwise independent of what follows them, which is what
-        # lets prefix reuse skip rewriting shared pages (see write_prefill).
-        # paged_impl picks the decode-attention implementation ("stream" =
-        # paged-native, "pallas" = TPU kernel, "gather" = legacy oracle);
-        # stream/gather are bit-identical, so prefix guarantees hold under
-        # any.  When both rt and paged_impl are given, paged_impl wins (an
-        # explicitly requested implementation must not be silently ignored).
-        self.rt = rt or Runtime(
-            remat="none",
-            block_q=16,
-            block_k=16,
-            scan_chunk=32,
-            page_size=page_size,
-            paged_impl=paged_impl or "stream",
-        )
-        if paged_impl is not None and self.rt.paged_impl != paged_impl:
-            import dataclasses
-
-            self.rt = dataclasses.replace(self.rt, paged_impl=paged_impl)
-        if self.rt.page_size != page_size:
+        rt = rt or self.default_runtime(page_size)
+        if rt.page_size != page_size:
             raise ValueError("Runtime.page_size must match engine page_size")
+        # the decode-attention implementation: an explicit paged_impl wins
+        # over the Runtime's, and with neither the backend and mesh decide
+        impl = paged_impl or rt.paged_impl or default_paged_impl(rt.mesh)
+        self.rt = dataclasses.replace(rt, paged_impl=impl)
+        logger.info(
+            "%s: paged decode impl=%s (backend=%s, mesh devices=%d)",
+            self.cfg.name, impl, jax.default_backend(), mesh_world_size(rt.mesh),
+        )
         self.lm = LM(self.cfg, self.rt)
-        self.params, _ = self.lm.init(jax.random.PRNGKey(seed))
+        # sharded data plane (DESIGN.md §13): when the Runtime carries a
+        # mesh, params and the paged cache are placed per the serving Rules
+        # (a one-device mesh pins the engine to that device) and the
+        # decode/chunk jits are explicitly sharded.  The host-side step loop
+        # is untouched — tokens/lengths/page tables are replicated, and the
+        # eager cache writers (write_prefill, restore_state) hand arrays
+        # back to the jit, whose in_shardings re-pin them.
+        self.plan = ShardingPlan.for_runtime(self.rt)
+        param_axes = self.lm.param_axes()
+        if params is None:
+            shardings = None
+            if self.plan is not None:
+                shardings = self.plan.param_sharding_tree(
+                    self.lm.param_shapes(), param_axes
+                )
+            params, _ = self.lm.init(jax.random.PRNGKey(seed), shardings)
+        elif self.plan is not None:
+            params = self.plan.shard_params(params, param_axes)
+        self.params = params
         self.max_batch = max_batch
         self.page_size = page_size
         self.max_seq = max_seq
@@ -155,12 +173,20 @@ class ServeEngine:
         )
         self.collect_logits = collect_logits
         self.axes = self.lm.cache_axes()
-        self.cache = init_paged_cache(
+        make_cache = functools.partial(
+            init_paged_cache,
             self.lm,
             num_pages=num_pages,
             page_size=page_size,
             max_batch=max_batch,
         )
+        cache_sh = None
+        if self.plan is not None:
+            cache_sh = self.plan.cache_sharding_tree(
+                jax.eval_shape(make_cache), self.axes
+            )
+        # built in place on the engine's devices, like the params
+        self.cache = jax.jit(make_cache, out_shardings=cache_sh)()
         self.page_tables = np.full(
             (max_batch, self.pages_per_seq), SCRATCH_PAGE, np.int32
         )
@@ -179,13 +205,6 @@ class ServeEngine:
         self._chunk = jax.jit(
             self.lm.prefill_chunk, static_argnames=("s0",), donate_argnums=(3,)
         )
-        # sharded data plane (DESIGN.md §13): when the Runtime carries a
-        # mesh, place params and the paged cache per the serving Rules and
-        # replace the decode/chunk jits with explicitly-sharded ones.  The
-        # host-side step loop is untouched — tokens/lengths/page tables are
-        # replicated, and the eager cache writers (write_prefill,
-        # restore_state) hand arrays back to the jit, whose in_shardings
-        # re-pin them.
         # every step timing rides the telemetry bus as a ServeStepEvent;
         # the deprecated ``telemetry`` property reconstructs legacy rows
         self.tracker = Tracker([MemorySink()])
@@ -206,10 +225,7 @@ class ServeEngine:
             else None
         )
         self.scheduler.tracer = self.spans
-        self.plan = ShardingPlan.for_runtime(self.rt)
         if self.plan is not None:
-            self.params = self.plan.shard_params(self.params, self.lm.param_axes())
-            self.cache = self.plan.shard_cache(self.cache, self.axes)
             self.page_tables_dev = self.plan.put_replicated(self.page_tables_dev)
             self._decode = self.plan.decode_jit(
                 self.lm, self.params, self.cache, tracer=self.spans
@@ -224,6 +240,19 @@ class ServeEngine:
     @staticmethod
     def config_for(arch: str, smoke: bool):
         return get_smoke_config(arch) if smoke else get_config(arch)
+
+    @staticmethod
+    def default_runtime(page_size: int = 16, **overrides) -> Runtime:
+        """The serving Runtime (``overrides``: e.g. ``mesh``, ``paged_impl``).
+
+        block_q = block_k = 16 pins the flash-attention blocking: the kernel
+        clamps blocks to min(block, max(seq, 16)), so 16 is the one setting
+        whose block grid never depends on prompt length.  That makes
+        prefix-position activations — and therefore shared prefix pages —
+        bitwise independent of what follows them, which is what lets prefix
+        reuse skip rewriting shared pages (see write_prefill)."""
+        return Runtime(remat="none", block_q=16, block_k=16, scan_chunk=32,
+                       page_size=page_size, **overrides)
 
     def _sp(self, name: str, **attrs):
         """Span scope when tracing is on, else a free no-op context."""

@@ -15,10 +15,10 @@ Given a ``Runtime`` carrying a mesh (and optionally explicit ``Rules`` —
   (cache donated), so every step runs partitioned instead of relying on
   sharding propagation from whatever the last host write left behind.
 
-Both paged-attention implementations ("stream" and "gather") run under the
-plan — they read the pool with gathers that partition trivially when the
-page axis is replicated.  The "pallas" kernel path is host-compiled and is
-rejected at world size > 1.
+Both jnp paged-attention implementations ("stream" and "gather") run under
+the plan — they read the pool with gathers that partition trivially when the
+page axis is replicated.  The "pallas" kernel is not partitioned: it runs on
+a one-device mesh and is rejected at world size > 1.
 
 The plan is geometry-only: it never copies weights itself until
 ``shard_params`` / ``shard_cache`` are called, so a CPU smoke engine on a
@@ -42,6 +42,16 @@ def mesh_world_size(mesh) -> int:
     return int(mesh.devices.size) if mesh is not None else 1
 
 
+def default_paged_impl(mesh) -> str:
+    """Paged decode attention the serve engine runs unless told otherwise:
+    the Pallas kernel on a TPU where the engine's state sits on one device,
+    the paged-native jnp ``stream`` path on other backends and on larger
+    meshes, where the kernel cannot be partitioned (see ``for_runtime``)."""
+    if jax.default_backend() == "tpu" and mesh_world_size(mesh) == 1:
+        return "pallas"
+    return "stream"
+
+
 @dataclasses.dataclass(frozen=True)
 class ShardingPlan:
     """Placement of one serve engine's state on one mesh."""
@@ -58,9 +68,8 @@ class ShardingPlan:
         rules = rt.rules or Rules.for_serving(rt.mesh)
         if rt.paged_impl == "pallas" and mesh_world_size(rt.mesh) > 1:
             raise ValueError(
-                "paged_impl='pallas' is host-compiled and cannot run "
-                "partitioned; use 'stream' or 'gather' on a multi-device "
-                "mesh"
+                "paged_impl='pallas' is not partitioned; use 'stream' or "
+                "'gather' on a multi-device mesh"
             )
         return cls(mesh=rt.mesh, rules=rules)
 

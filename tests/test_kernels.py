@@ -10,7 +10,9 @@ from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.flash_attention.ops import decode_attention, flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.flash_decode.kernel import flash_decode_pallas
-from repro.kernels.sdca.kernel import local_sdca_pallas
+from repro.kernels import pallas_interpret
+from repro.kernels.sdca.kernel import VMEM_CAPACITY, local_sdca_pallas, vmem_bytes
+from repro.kernels.sdca.ops import local_sdca
 from repro.kernels.sdca.ref import local_sdca_ref
 from repro.kernels.ssm_scan.kernel import selective_scan_pallas
 from repro.kernels.ssm_scan.ops import selective_scan, selective_scan_step
@@ -212,3 +214,29 @@ def test_sdca_pallas_vs_ref(sigma):
         Xk, yk, ak_, w, ik, sigma, 1e-3, float(m * nl)))(X, y, a, idx)
     np.testing.assert_allclose(np.asarray(ak), np.asarray(ar), atol=1e-4)
     np.testing.assert_allclose(np.asarray(dwk), np.asarray(dwr), atol=1e-4)
+
+
+def test_local_sdca_refuses_a_shard_the_kernel_cannot_hold():
+    """Asked for the kernel, ``local_sdca`` raises for a shard whose blocks
+    exceed VMEM (CoCoA MNIST on 4 workers) instead of running the reference."""
+    m, nl, d = 4, 15000, 784
+    assert vmem_bytes(nl, d) > VMEM_CAPACITY
+    args = (jax.ShapeDtypeStruct((m, nl, d), jnp.float32),
+            jax.ShapeDtypeStruct((m, nl), jnp.float32),
+            jax.ShapeDtypeStruct((m, nl), jnp.float32),
+            jax.ShapeDtypeStruct((d,), jnp.float32),
+            jax.ShapeDtypeStruct((m, nl), jnp.int32))
+    with pytest.raises(ValueError, match="VMEM"):
+        jax.eval_shape(lambda *a: local_sdca(*a, 1.0, 1e-4, 60000.0,
+                                             use_pallas=True), *args)
+    out = jax.eval_shape(lambda *a: local_sdca(*a, 1.0, 1e-4, 60000.0), *args)
+    assert out[0].shape == (m, nl) and out[1].shape == (m, d)
+
+
+def test_pallas_interpret_never_interprets_on_a_tpu(monkeypatch):
+    assert pallas_interpret() is True  # the CPU backend here
+    assert pallas_interpret(False) is False  # an AOT compile for a TPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pallas_interpret() is False
+    with pytest.raises(ValueError, match="TPU"):
+        pallas_interpret(True)
