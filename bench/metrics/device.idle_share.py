@@ -1,0 +1,8 @@
+"""Share of the traced window in which no operation ran on the device, in
+%, averaged over the cell's chips.  Moves itl_p50_ms."""
+
+
+def read(run):
+    if run.trace is None or not run.trace.window_s:
+        return None
+    return 100.0 * (1.0 - run.trace.busy_s / run.trace.window_s)
