@@ -1,0 +1,10 @@
+"""Median ``engine.sample`` span in ms (the host's argmax over each decode
+row's logits and the commit of its token), over the decode steps in the
+traced window, from the program's spans in the profiler trace.  Moves
+itl_p50_ms."""
+from bench import program_spans
+
+
+def read(run):
+    phases = program_spans.of_run(run)
+    return None if phases is None else phases.median_ms("engine.sample")

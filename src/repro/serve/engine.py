@@ -227,11 +227,9 @@ class ServeEngine:
         self.scheduler.tracer = self.spans
         if self.plan is not None:
             self.page_tables_dev = self.plan.put_replicated(self.page_tables_dev)
-            self._decode = self.plan.decode_jit(
-                self.lm, self.params, self.cache, tracer=self.spans
-            )
+            self._decode = self.plan.decode_jit(self.lm, self.params, self.cache)
             self._chunk = self.plan.prefill_chunk_jit(
-                self.lm, self.params, self.cache, tracer=self.spans
+                self.lm, self.params, self.cache
             )
         self.step_count = 0
         self._rid = 0
@@ -305,6 +303,7 @@ class ServeEngine:
                 self.cache = restore_state(
                     self.cache, req.full_entry.state, self.axes, slot
                 )
+            self._activate(req, logits, n_front)
         else:
             fe = (
                 None
@@ -332,7 +331,13 @@ class ServeEngine:
                 page_size=self.page_size,
                 skip_pages=req.n_shared_pages,
             )
-            logits = np.asarray(logits_dev[0])
+            self._finish_prefill(req, logits_dev, (0,), n_front)
+
+    def _finish_prefill(self, req: Request, logits_dev, index, n_front: int) -> None:
+        """Fetch the prompt's last-token logits (``logits_dev[index]``),
+        register its pages with the prefix cache and arm the slot."""
+        with self._sp("activate", component="engine.activate", rid=req.rid):
+            logits = np.asarray(logits_dev[index])
             if self.prefix is not None and req.frontend_embeds is None:
                 n_prompt_pages = -(-len(req.prompt) // self.page_size)
                 self.prefix.register(
@@ -342,10 +347,10 @@ class ServeEngine:
                     req.prompt,
                     req.page_ids[: len(req.prompt) // self.page_size],
                     logits,
-                    snapshot_state(self.cache, self.axes, slot),
+                    snapshot_state(self.cache, self.axes, req.slot),
                     self.pool,
                 )
-        self._activate(req, logits, n_front)
+            self._activate(req, logits, n_front)
 
     def _activate(self, req: Request, logits: np.ndarray, n_front: int) -> None:
         """Seed the first token from prefill logits and arm the decode slot."""
@@ -411,26 +416,23 @@ class ServeEngine:
         req.prefill_pos += n_tokens
         self._emit("prefill", batch=0, step_s=dt, prefill_tokens=n_tokens)
         if req.prefill_pos >= len(req.prompt):
-            logits = np.asarray(logits_dev[0, n_tokens - 1])
-            if self.prefix is not None:
-                n_prompt_pages = -(-len(req.prompt) // self.page_size)
-                self.prefix.register(
-                    req.prompt, req.page_ids[:n_prompt_pages], self.pool
-                )
-                self.prefix.register_full(
-                    req.prompt,
-                    req.page_ids[: len(req.prompt) // self.page_size],
-                    logits,
-                    snapshot_state(self.cache, self.axes, slot),
-                    self.pool,
-                )
-            self._activate(req, logits, 0)
+            self._finish_prefill(req, logits_dev, (0, n_tokens - 1), 0)
 
     def _release_slot(self, slot: int) -> None:
         self.lengths[slot] = 0
         self.next_tokens[slot] = 0
         self.page_tables[slot] = SCRATCH_PAGE
         self.page_tables_dev = self.page_tables_dev.at[slot].set(SCRATCH_PAGE)
+
+    def _retire(self, reqs: List[Request]) -> None:
+        """Evict the finished requests: their slots and pages go free."""
+        if not reqs:
+            return
+        with self._sp("retire", component="engine.retire", n=len(reqs)):
+            for req in reqs:
+                slot = req.slot
+                self.scheduler.finish(req, self.step_count)
+                self._release_slot(slot)
 
     # ------------------------------------------------------------------
     def step(self) -> int:
@@ -442,22 +444,23 @@ class ServeEngine:
             return self._step_inner()
 
     def _step_inner(self) -> int:
-        for req in self.scheduler.admit_ready(self.step_count):
-            if self._use_chunked(req):
-                req.state = RequestState.PREFILLING
-                req.prefill_pos = req.n_shared_pages * self.page_size
-            else:
-                self._admit(req)
-                if req.done:  # max_new_tokens == 1: prefill already finished
-                    slot = req.slot
-                    self.scheduler.finish(req, self.step_count)
-                    self._release_slot(slot)
-        for req, take in self.scheduler.plan_prefill():
+        with self._sp("schedule", component="engine.schedule"):
+            whole = []  # admitted requests prefilled in one call
+            for req in self.scheduler.admit_ready(self.step_count):
+                if self._use_chunked(req):
+                    req.state = RequestState.PREFILLING
+                    req.prefill_pos = req.n_shared_pages * self.page_size
+                else:
+                    whole.append(req)
+            chunks = self.scheduler.plan_prefill()
+        for req in whole:
+            self._admit(req)
+            if req.done:  # max_new_tokens == 1: prefill already finished
+                self._retire([req])
+        for req, take in chunks:
             self._prefill_chunk_step(req, take)
             if req.state is RequestState.RUNNING and req.done:
-                slot = req.slot
-                self.scheduler.finish(req, self.step_count)
-                self._release_slot(slot)
+                self._retire([req])
         decoding = self.scheduler.decoding
         if not decoding:
             self.step_count += 1
@@ -469,30 +472,34 @@ class ServeEngine:
             return n
         t0 = time.perf_counter()
         with self._sp("decode", component="engine.decode", batch=len(decoding)):
-            logits_dev, self.cache = self._decode(
-                self.params,
-                jnp.asarray(self.next_tokens),
-                jnp.asarray(self.lengths),
-                self.cache,
-                self.page_tables_dev,
-            )
-            logits_np = np.asarray(logits_dev)
+            with self._sp("launch", component="engine.decode.launch"):
+                logits_dev, self.cache = self._decode(
+                    self.params,
+                    jnp.asarray(self.next_tokens),
+                    jnp.asarray(self.lengths),
+                    self.cache,
+                    self.page_tables_dev,
+                )
+            with self._sp("wait", component="engine.decode.wait"):
+                logits_dev.block_until_ready()
+            with self._sp("fetch", component="engine.decode.fetch"):
+                logits_np = np.asarray(logits_dev)
         dt = time.perf_counter() - t0
         self._emit(
             "decode", batch=len(decoding), step_s=dt, committed=len(decoding)
         )
-        for req in decoding:
-            slot = req.slot
-            tok = int(np.argmax(logits_np[slot]))
-            req.generated.append(tok)
-            if req.logits_trace is not None:
-                req.logits_trace.append(logits_np[slot].astype(np.float32).copy())
-            self.lengths[slot] += 1
-            self.next_tokens[slot] = tok
-            if req.done:
-                slot_to_clear = req.slot
-                self.scheduler.finish(req, self.step_count)
-                self._release_slot(slot_to_clear)
+        with self._sp("sample", component="engine.sample", batch=len(decoding)):
+            for req in decoding:
+                slot = req.slot
+                tok = int(np.argmax(logits_np[slot]))
+                req.generated.append(tok)
+                if req.logits_trace is not None:
+                    req.logits_trace.append(
+                        logits_np[slot].astype(np.float32).copy()
+                    )
+                self.lengths[slot] += 1
+                self.next_tokens[slot] = tok
+        self._retire([req for req in decoding if req.done])
         self.step_count += 1
         return len(decoding)
 

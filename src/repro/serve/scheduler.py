@@ -82,8 +82,7 @@ class Scheduler:
         self.slots: List[Optional[Request]] = [None] * max_batch
         self.finished: List[Request] = []
         # optional SpanTracer (set by the owning engine when tracing is on):
-        # admissions emit scheduler.join spans carrying the queue wait,
-        # page accounting emits pages.alloc / pages.evict spans
+        # admissions emit scheduler.join spans carrying the queue wait
         self.tracer: Optional[Any] = None
 
     # ------------------------------------------------------------------
@@ -206,16 +205,6 @@ class Scheduler:
     def _allocate(self, req: Request) -> bool:
         """Reserve pages for the request's whole lifetime (prompt + frontend
         + max_new_tokens), reusing shared prefix pages where possible."""
-        if self.tracer is not None:
-            with self.tracer.span(
-                "page_alloc", component="pages.alloc", rid=req.rid
-            ) as h:
-                ok = self._allocate_inner(req)
-                h.set(ok=ok, pages=len(req.page_ids), shared=req.n_shared_pages)
-            return ok
-        return self._allocate_inner(req)
-
-    def _allocate_inner(self, req: Request) -> bool:
         shared: List[int] = []
         use_prefix = self.prefix is not None and req.frontend_embeds is None
         if use_prefix:
@@ -250,16 +239,6 @@ class Scheduler:
         req.state = RequestState.FINISHED
         req.finished_step = now
         self.slots[req.slot] = None
-        if self.tracer is not None:
-            with self.tracer.span(
-                "page_evict",
-                step=now,
-                component="pages.evict",
-                rid=req.rid,
-                pages=len(req.page_ids),
-            ):
-                self.pool.free(req.page_ids)
-        else:
-            self.pool.free(req.page_ids)
+        self.pool.free(req.page_ids)
         req.page_ids = []
         self.finished.append(req)

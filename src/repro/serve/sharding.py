@@ -110,41 +110,21 @@ class ShardingPlan:
         return jax.device_put(x, self.replicated())
 
     # ------------------------------------------------------------------
-    def _dispatch_span(self, tracer, jitted, name: str):
-        """Wrap a sharded jit so each dispatch emits a trace span.
-
-        The span covers the partitioned *dispatch* (argument transfer +
-        launch), not device completion — jax returns before the collective
-        finishes, so the enclosing engine scope (which blocks) carries the
-        wall time while this span shows the launch overhead per step."""
-        world = mesh_world_size(self.mesh)
-
-        def dispatched(*args, **kwargs):
-            with tracer.span(
-                name, component="sharding.dispatch", world=world
-            ):
-                return jitted(*args, **kwargs)
-
-        return dispatched
-
-    def decode_jit(self, lm, params: Any, cache: Any, tracer: Any = None):
+    def decode_jit(self, lm, params: Any, cache: Any):
         """``LM.decode_step_paged`` jitted with explicit shardings:
         (params, tokens, lengths, cache, page_tables) -> (logits, cache),
         cache donated, logits replicated (the engine argmaxes on host)."""
         param_sh = self.param_sharding_tree(params, lm.param_axes())
         cache_sh = self.cache_sharding_tree(cache, lm.cache_axes())
         rep = self.replicated()
-        jitted = jax.jit(
+        return jax.jit(
             lm.decode_step_paged,
             in_shardings=(param_sh, rep, rep, cache_sh, rep),
             out_shardings=(rep, cache_sh),
             donate_argnums=(3,),
         )
-        if tracer is None:
-            return jitted
-        return self._dispatch_span(tracer, jitted, "sharded_decode")
 
-    def prefill_chunk_jit(self, lm, params: Any, cache: Any, tracer: Any = None):
+    def prefill_chunk_jit(self, lm, params: Any, cache: Any):
         """``LM.prefill_chunk`` jitted with the same cache placement (chunk
         logits replicated; ``s0`` static as in the unsharded jit).  pjit
         rejects kwargs once ``in_shardings`` is given, so ``s0`` becomes a
@@ -166,6 +146,4 @@ class ShardingPlan:
         def chunk(params, tokens, n_tokens, cache, rows, *, s0):
             return jitted(params, tokens, n_tokens, cache, rows, s0)
 
-        if tracer is None:
-            return chunk
-        return self._dispatch_span(tracer, chunk, "sharded_prefill_chunk")
+        return chunk

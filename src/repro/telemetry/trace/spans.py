@@ -17,6 +17,11 @@ traces replayable:
 Spans nest via an explicit stack: the innermost open span is the parent
 of the next one opened.  Events are emitted in close order (children
 before parents), which every reader here handles.
+
+A ``span()`` scope is also a ``jax.profiler.TraceAnnotation`` named by
+its component, so it lands on the host timeline of any active profiler
+session, on the clock of the device's ops; with no session it records
+nothing.  ``emit_span`` (modeled or pre-measured time) writes none.
 """
 
 from __future__ import annotations
@@ -35,6 +40,14 @@ def det_id(*parts: Any) -> str:
     """16-hex-char blake2b digest of the given parts — a deterministic ID."""
     h = hashlib.blake2b("/".join(str(p) for p in parts).encode(), digest_size=8)
     return h.hexdigest()
+
+
+def _profiler_scope(name: str):
+    """``jax.profiler.TraceAnnotation(name)``; jax is imported on first
+    use, as the bus itself has no jax dependency."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(name)
 
 
 class CountingClock:
@@ -164,7 +177,8 @@ class SpanTracer:
         )
         self._stack.append(frame)
         try:
-            yield SpanHandle(frame)
+            with _profiler_scope(frame.component):
+                yield SpanHandle(frame)
         finally:
             self._stack.pop()
             self._emit(frame, self.now() - frame.t0)

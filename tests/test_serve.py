@@ -217,6 +217,55 @@ def test_full_prompt_reuse_with_mamba_state():
         np.testing.assert_array_equal(got, want)
 
 
+# --------------------------------------------------------- step phase spans
+def _serve_chunked(trace: bool):
+    from repro.telemetry.trace import CountingClock
+
+    eng = ServeEngine(ARCH, prefill_chunk=4, trace=trace,
+                      trace_clock=CountingClock(), **GEOM)
+    rng = np.random.RandomState(5)
+    reqs = [eng.submit(_prompt(rng, 11), max_new_tokens=3),
+            eng.submit(_prompt(rng, 6), max_new_tokens=6)]
+    eng.run()
+    return eng, [r.generated for r in reqs]
+
+
+def test_engine_step_phases_are_child_spans_in_order():
+    eng, _ = _serve_chunked(trace=True)
+    spans = eng.events("span")
+
+    def kids(parent):
+        inside = [e for e in spans if e.parent_id == parent.span_id]
+        for e in inside:
+            assert parent.t0 <= e.t0 and e.t0 + e.dur <= parent.t0 + parent.dur
+        return sorted(inside, key=lambda e: e.t0)
+
+    steps = [e for e in spans if e.component == "engine.step"]
+    assert all(not e.parent_id for e in steps)
+    # the last step decodes the one request left and retires it
+    last = kids(max(steps, key=lambda e: e.step))
+    assert [e.component for e in last] == [
+        "engine.schedule", "engine.decode", "engine.sample", "engine.retire"]
+    assert [e.component for e in kids(last[1])] == [
+        "engine.decode.launch", "engine.decode.wait", "engine.decode.fetch"]
+    # a prompt's last chunk hands its first token over in engine.activate
+    phases = {e.step: [k.component for k in kids(e)] for e in steps}
+    assert any(p[:3] == ["engine.schedule", "engine.prefill_chunk",
+                         "engine.activate"] for p in phases.values())
+    assert not {"pages.alloc", "pages.evict"} & {e.component for e in spans}
+
+
+def test_engine_without_tracing_emits_no_span_and_same_tokens():
+    off, tokens_off = _serve_chunked(trace=False)
+    on, tokens_on = _serve_chunked(trace=True)
+    assert off.spans is None and not off.events("span") and on.events("span")
+    assert tokens_off == tokens_on
+    assert [(e.step, e.op, e.batch) for e in off.events("serve_step")] == [
+        (e.step, e.op, e.batch) for e in on.events("serve_step")]
+    np.testing.assert_array_equal(off.page_tables, on.page_tables)
+    np.testing.assert_array_equal(off.lengths, on.lengths)
+
+
 # ---------------------------------------------------------- capacity planner
 def test_capacity_planner_fit_query_roundtrip():
     # synthetic telemetry from a known affine step model t(b) = a + c*b

@@ -84,6 +84,78 @@ def test_same_seed_perfetto_exports_are_byte_identical(tmp_path):
     assert paths[0].read_bytes() == paths[1].read_bytes()
 
 
+# ------------------------------------------------- the profiler's own trace
+def _profiled(log_dir, body):
+    """Run ``body()`` inside a profiler session writing under ``log_dir``;
+    returns the host events of the trace, by name."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(log_dir))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(log_dir / "**" / "*.xplane.pb"), recursive=True)
+    return {
+        e.name: (e.start_ns, e.start_ns + e.duration_ns)
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for e in line.events
+    }
+
+
+def test_span_lands_in_the_profiler_trace_inside_its_parent(tmp_path):
+    tr = SpanTracer(trace=("prof",), clock=CountingClock())
+
+    def body():
+        with tr.span("step", component="engine.step"):
+            with tr.span("fetch", component="engine.decode.fetch"):
+                sum(range(1000))
+
+    host = _profiled(tmp_path, body)
+    (s0, s1), (c0, c1) = host["engine.step"], host["engine.decode.fetch"]
+    assert s0 <= c0 < c1 <= s1
+    # the bus still carries both spans, the child first
+    assert [e.component for e in tr.tracker.events("span")] == [
+        "engine.decode.fetch", "engine.step"]
+
+
+def test_emit_span_writes_no_profiler_event(tmp_path):
+    tr = SpanTracer(trace=("prof",), clock=CountingClock())
+
+    def body():
+        with tr.span("tick", component="fleet.tick"):
+            tr.emit_span("job", dur=300.0, component="fleet.train")
+
+    host = _profiled(tmp_path, body)
+    assert "fleet.tick" in host and "fleet.train" not in host
+    assert len(tr.tracker.events("span")) == 2
+
+
+def test_counting_clock_trace_is_byte_identical_under_the_profiler(tmp_path):
+    """Two same-seed runs, one inside a profiler session: the spans on the
+    bus do not see the profiler."""
+    def run(i):
+        tr = SpanTracer(trace=("run", 7), clock=CountingClock())
+        with tr.span("step", step=0, component="engine.step"):
+            with tr.span("sample", step=0, component="engine.sample", batch=2):
+                pass
+            tr.emit_span("join", dur=0.0, step=0, component="scheduler.join")
+        p = tmp_path / f"trace_{i}.jsonl"
+        tr.tracker.to_jsonl(p)
+        return p.read_bytes()
+
+    plain = run(0)
+    out = {}
+    host = _profiled(tmp_path / "prof", lambda: out.update(bytes=run(1)))
+    assert "engine.sample" in host
+    assert out["bytes"] == plain
+
+
 def test_set_trace_rekeys_only_before_first_span():
     tr = SpanTracer(trace=("serve", "m", 0, -1))
     old = tr.trace_id
