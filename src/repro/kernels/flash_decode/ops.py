@@ -8,10 +8,10 @@ page table.  Three implementations share one blocking scheme
 associativity.  ``stream`` and ``gather`` are **bit-identical** under any
 page table / fill / blocking (tests assert it — this is what lets the
 engine switch between them without perturbing prefix-cache guarantees);
-the Pallas kernel computes the same blocked math at the same
-``Precision.HIGHEST`` dots and matches them to float exactness (its
-per-program 2D dots may accumulate in another order than the batched
-einsum, so the last ulp is not contractual there):
+the Pallas kernel computes the same blocked math with float32 products
+and sums and matches them to float exactness (its head-batched dots may
+accumulate in another order than the einsum, so the last ulp is not
+contractual there):
 
 * ``stream`` — paged-native jnp: a bounded loop gathers only the current
   group's pages ((B, ppp, Hk, page, d)) and runs an online softmax; the
@@ -19,7 +19,9 @@ einsum, so the last ulp is not contractual there):
   not O(cache capacity).  No (B, Hk, P*page, d) dense KV intermediate ever
   exists in the jaxpr.  This is the engine's CPU path.
 * ``pallas`` — ``paged_flash_decode_pallas``: same algorithm with the page
-  table as a scalar-prefetch operand and pages streamed through VMEM.
+  table as a scalar-prefetch operand and pages streamed through VMEM, on a
+  grid of (B, page groups): one program per row and group of
+  ``pages_per_program`` pages, over all KV heads at once.
   This is the engine's path on a TPU; on the CPU it runs in interpret
   mode, which the tests use as its correctness proxy.
 * ``gather`` — the legacy fallback and correctness oracle: materializes
@@ -47,7 +49,9 @@ from repro.kernels.flash_decode.kernel import (
 
 NEG_INF = -1e30
 PAGED_IMPLS = ("stream", "pallas", "gather")
-DEFAULT_PAGES_PER_PROGRAM = 4
+# the fastest Pallas blocking of 4, 8 and 16 on a TPU v5e at stablelm-1.6b's
+# serving shapes (4 ties it when a third of the rows are live)
+DEFAULT_PAGES_PER_PROGRAM = 8
 DEFAULT_PREFILL_CHUNK = 32
 
 

@@ -86,8 +86,10 @@ def estimate(family: str, shape: Dict[str, int], config: Dict[str, int]) -> Cand
         s = npp * page
         flops = 4.0 * b * hk * g * s * d
         bytes_moved = 2.0 * b * hk * s * d * it
-        vmem = (2 * ppp * page * d + g * d + g * ppp * page) * it
-        steps = b * hk * _ceil_div(npp, ppp)
+        # one program per (row, page group), over every KV head: ppp K and V
+        # pages of all heads, the scores, and the hk-wide acc/m/l scratch
+        vmem = (2 * ppp * hk * page * d + hk * g * ppp * page + hk * g * (d + 2)) * it
+        steps = b * _ceil_div(npp, ppp)
     elif family == "prefill_chunk":
         p, hk, g = shape["p"], shape["hk"], shape["g"]
         d, page, npp = shape["d"], shape["page"], shape["npp"]

@@ -8,9 +8,9 @@ pages_per_program.  That exactness is what lets the engine switch
 implementations without perturbing prefix-cache guarantees (tested
 end-to-end: a stream engine and a gather engine serve identical traces
 token-for-token and logit-for-logit).  The Pallas kernel runs the same
-blocked math at the same dot precision and must match to float exactness
-(its per-program 2D dots may accumulate in another order, so the last
-ulp is not contractual)."""
+blocked math with float32 products and sums and must match to float
+exactness (its head-batched dots may accumulate in another order, so the
+last ulp is not contractual)."""
 import jax
 import jax.extend.core as jax_core
 import jax.numpy as jnp
@@ -38,7 +38,7 @@ def _assert_impls_agree(outs):
 
 
 def _paged_inputs(seed, b=3, hk=2, g=2, d=16, page=8, npp=6, n_pages=32,
-                  dtype=jnp.float32, share_prefix=True):
+                  dtype=jnp.float32, share_prefix=True, lens=None):
     rng = np.random.RandomState(seed)
     q = jnp.asarray(rng.randn(b, hk * g, d), dtype)
     kp = jnp.asarray(rng.randn(n_pages, hk, page, d), dtype)
@@ -47,45 +47,76 @@ def _paged_inputs(seed, b=3, hk=2, g=2, d=16, page=8, npp=6, n_pages=32,
                     for _ in range(b)])
     if share_prefix and b > 1:
         pts[1][:2] = pts[0][:2]  # two rows share their first two pages
-    lens = np.asarray([1 + rng.randint(npp * page) for _ in range(b)],
-                      np.int32)
+    if lens is None:
+        lens = [1 + rng.randint(npp * page) for _ in range(b)]
+    lens = np.asarray(lens, np.int32)
     return q, kp, vp, jnp.asarray(lens), jnp.asarray(pts, jnp.int32)
 
 
+def _ragged_lens(ppp, page, npp):
+    """Rows ending on a group boundary, mid-page, on the row's capacity,
+    and an idle row (length 1)."""
+    return [ppp * page, ppp * page + 3, npp * page, 1]
+
+
+# (pages_per_program, KV heads, G): the first three are small-GQA cases at
+# random lengths; the rest run four KV heads, one query each (G == 1, the
+# kernel's VPU math) and two (G > 1, its head-batched MXU dots), at the
+# production blockings over ragged lengths
+PAGED_CASES = [pytest.param(p, 2, 2, id=str(p)) for p in (1, 3, 6)] + [
+    pytest.param(p, 4, g, id=f"{p}-hk4-g{g}") for g in (1, 2) for p in (4, 8, 16)]
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("ppp", [1, 3, 6])
-def test_paged_impls_bit_identical(dtype, ppp):
-    q, kp, vp, lens, pt = _paged_inputs(0, dtype=dtype)
+@pytest.mark.parametrize("ppp,hk,g", PAGED_CASES)
+def test_paged_impls_bit_identical(dtype, ppp, hk, g):
+    if hk == 2:
+        q, kp, vp, lens, pt = _paged_inputs(0, dtype=dtype)
+    else:
+        page, npp = 8, 32
+        q, kp, vp, lens, pt = _paged_inputs(
+            ppp, b=4, hk=hk, g=g, page=page, npp=npp, n_pages=64, dtype=dtype,
+            lens=_ragged_lens(ppp, page, npp))
     outs = {impl: np.asarray(paged_decode_attention(
         q, kp, vp, lens, pt, impl=impl, pages_per_program=ppp))
         for impl in IMPLS}
     _assert_impls_agree(outs)
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_latent_impls_bit_identical(dtype):
+@pytest.mark.parametrize("dtype,ppp", [
+    pytest.param(jnp.float32, 2, id="float32"),
+    pytest.param(jnp.bfloat16, 2, id="bfloat16")] + [
+    pytest.param(jnp.bfloat16, p, id=f"bfloat16-ppp{p}") for p in (4, 8, 16)])
+def test_paged_latent_impls_bit_identical(dtype, ppp):
     rng = np.random.RandomState(1)
-    b, h, r, rope, page, npp, n_pages = 3, 4, 16, 8, 8, 6, 32
+    b, h, r, rope, page, n_pages = 3, 4, 16, 8, 8, 32
+    npp = 6 if ppp == 2 else 16
     q_lat = jnp.asarray(rng.randn(b, h, r), dtype)
     q_pe = jnp.asarray(rng.randn(b, h, rope), dtype)
     ckv = jnp.asarray(rng.randn(n_pages, page, r), dtype)
     kpe = jnp.asarray(rng.randn(n_pages, page, rope), dtype)
-    pt = jnp.asarray(np.stack([rng.choice(n_pages, npp, replace=False)
-                               for _ in range(b)]), jnp.int32)
-    lens = jnp.asarray([5, 17, 41], jnp.int32)
+    pts = np.stack([rng.choice(n_pages, npp, replace=False) for _ in range(b)])
+    if ppp == 2:
+        lens = [5, 17, 41]
+    else:  # a group boundary, the row's capacity, an idle row; shared prefix
+        pts[1][:2] = pts[0][:2]
+        lens = [ppp * page, npp * page, 1]
+    pt = jnp.asarray(pts, jnp.int32)
+    lens = jnp.asarray(lens, jnp.int32)
     outs = {impl: np.asarray(paged_latent_decode_attention(
         q_lat, q_pe, ckv, kpe, lens, pt, sm_scale=0.2, impl=impl,
-        pages_per_program=2)) for impl in IMPLS}
+        pages_per_program=ppp)) for impl in IMPLS}
     _assert_impls_agree(outs)
 
 
 @settings(max_examples=12, deadline=None)
 @given(st.integers(0, 2 ** 31 - 1), st.sampled_from([1, 2, 4, 5]),
-       st.sampled_from([1, 2, 3]))
-def test_paged_property_bit_identical(seed, ppp, g):
+       st.sampled_from([1, 2, 3]), st.sampled_from([1, 2, 4]))
+def test_paged_property_bit_identical(seed, ppp, g, hk):
     """Property: stream == gather bitwise (pallas to float exactness) for
-    random page tables, shared prefix pages, and ragged lengths."""
-    q, kp, vp, lens, pt = _paged_inputs(seed, g=g, npp=5)
+    random page tables, shared prefix pages, ragged lengths, and KV head
+    counts."""
+    q, kp, vp, lens, pt = _paged_inputs(seed, hk=hk, g=g, npp=5)
     outs = {impl: np.asarray(paged_decode_attention(
         q, kp, vp, lens, pt, impl=impl, pages_per_program=ppp))
         for impl in IMPLS}

@@ -18,6 +18,9 @@ V5E_HBM_BYTES = 16 * 10**9
 # stablelm-1.6b serving geometry: max_batch 8, 32 KV heads of 64, page 16,
 # max_seq 544 (512-token prompts + 32 new tokens)
 B, HK, G, D, PAGE, NPP = 8, 32, 1, 64, 16, 34
+# the chat benchmark cell's: 16 slots, max_seq 1024, 577 pages
+CHAT = dict(b=16, npp=64, n_pages=577)
+SERVE = dict(b=B, npp=NPP, n_pages=1 + B * NPP)
 
 
 @pytest.fixture(scope="module")
@@ -59,19 +62,21 @@ def _device_bytes(compiled) -> int:
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
 
 
-@pytest.mark.parametrize("pages_per_program", [1, 4, 8])
+@pytest.mark.parametrize("pages_per_program,geom", [
+    pytest.param(p, SERVE, id=str(p)) for p in (1, 4, 8)] + [
+    pytest.param(p, CHAT, id=f"chat-{p}") for p in (4, 8, 16)])
 def test_paged_decode_kernel_compiles_at_stablelm_shapes(
-        one_chip, no_persistent_cache, pages_per_program):
+        one_chip, no_persistent_cache, pages_per_program, geom):
     from repro.kernels.flash_decode.kernel import paged_flash_decode_pallas
 
-    n_pages = 1 + B * NPP
-    pool = _spec(one_chip, (n_pages, HK, PAGE, D), jnp.bfloat16)
+    b, npp = geom["b"], geom["npp"]
+    pool = _spec(one_chip, (geom["n_pages"], HK, PAGE, D), jnp.bfloat16)
     fn = jax.jit(lambda q, k, v, lens, pt: paged_flash_decode_pallas(
         q, k, v, lens, pt, pages_per_program=pages_per_program,
         interpret=False))
     compiled = fn.lower(
-        _spec(one_chip, (B, HK, G, D), jnp.bfloat16), pool, pool,
-        _spec(one_chip, (B,), jnp.int32), _spec(one_chip, (B, NPP), jnp.int32),
+        _spec(one_chip, (b, HK, G, D), jnp.bfloat16), pool, pool,
+        _spec(one_chip, (b,), jnp.int32), _spec(one_chip, (b, npp), jnp.int32),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
@@ -94,9 +99,12 @@ def test_sdca_kernel_compiles_at_cocoa_shard(one_chip, no_persistent_cache):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("step", ["decode", "prefill_chunk"])
+@pytest.mark.parametrize("step,geom", [
+    pytest.param("decode", SERVE, id="decode"),
+    pytest.param("prefill_chunk", SERVE, id="prefill_chunk"),
+    pytest.param("decode", CHAT, id="decode-chat")])
 def test_stablelm_serve_step_fits_one_v5e(one_chip, no_persistent_cache,
-                                          monkeypatch, step):
+                                          monkeypatch, step, geom):
     """One whole 24-layer serve step at the engine's geometry compiles for
     one chip, with the paged Pallas kernel native (the backend here is the
     CPU, so the test itself turns interpret mode off), and its arguments,
@@ -113,16 +121,17 @@ def test_stablelm_serve_step_fits_one_v5e(one_chip, no_persistent_cache,
     place = lambda tree: jax.tree.map(
         lambda s: _spec(one_chip, s.shape, s.dtype), tree)
     params = place(lm.param_shapes())
+    b, npp = geom["b"], geom["npp"]
     cache = place(jax.eval_shape(lambda: init_paged_cache(
-        lm, num_pages=1 + B * NPP, page_size=PAGE, max_batch=B)))
+        lm, num_pages=geom["n_pages"], page_size=PAGE, max_batch=b)))
     i32 = lambda *shape: _spec(one_chip, shape, jnp.int32)
     if step == "decode":
         lowered = jax.jit(lm.decode_step_paged, donate_argnums=(3,)).lower(
-            params, i32(B), i32(B), cache, i32(B, NPP))
+            params, i32(b), i32(b), cache, i32(b, npp))
     else:
         lowered = jax.jit(lm.prefill_chunk, static_argnames=("s0",),
                           donate_argnums=(3,)).lower(
-            params, i32(1, 256), i32(), cache, i32(1, NPP), s0=256)
+            params, i32(1, 256), i32(), cache, i32(1, npp), s0=256)
     compiled = lowered.compile()
     assert ("tpu_custom_call" in compiled.as_text()) == (step == "decode")
     assert _device_bytes(compiled) < V5E_HBM_BYTES

@@ -127,6 +127,23 @@ def test_roofline_estimates_monotone_in_work():
         small.flops, small.bytes_moved)
 
 
+@pytest.mark.parametrize("ppp", [4, 8, 16])
+def test_roofline_paged_decode_one_program_per_row_group(ppp):
+    """The paged-decode model follows the kernel's grid: one program per
+    (row, page group) over all KV heads, so VMEM grows with Hk and the
+    step count does not."""
+    shape = {"b": 16, "hk": 32, "g": 1, "d": 64, "page": 16, "npp": 64}
+    est = estimate("flash_decode_paged", shape, {"pages_per_program": ppp})
+    assert est.grid_steps == 16 * (64 // ppp)
+    assert est.vmem_bytes == (2 * ppp * 32 * 16 * 64 + 32 * ppp * 16
+                              + 32 * 66) * 4
+    assert est.vmem_bytes <= VMEM_BUDGET
+    one_head = estimate("flash_decode_paged", {**shape, "hk": 1},
+                        {"pages_per_program": ppp})
+    assert one_head.grid_steps == est.grid_steps
+    assert est.vmem_bytes > 16 * one_head.vmem_bytes
+
+
 # --------------------------------------------------------------- telemetry
 def _cache_with_decode_entries():
     cache = ConfigCache(path=None)
