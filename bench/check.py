@@ -2,7 +2,8 @@
 
 After the window, a sample of the requests it finished (drawn from the
 seed, with the request that served the most tokens always in it) is run
-once through the float32 reference over its prompt and served tokens.  At
+once through the float32 reference of the configuration's block module
+(``blocks.forward``) over its prompt and served tokens.  At
 every served position the reading is how far the served token's reference
 logit lies below the reference's best, relative to the row's largest
 |logit|; the widest over the sample (``served_gap``) is held to the cell's
@@ -47,11 +48,9 @@ def sample(finished, seed: int, k: int) -> List[Served]:
                    np.asarray(finished[i].generated, np.int32)) for i in pick]
 
 
-def _reference_rows(w, cfg, s: Served, pad_to: int, precision: str):
+def _reference_rows(blocks, w, cfg, s: Served, pad_to: int, precision: str):
     """Reference logits at the positions that predict each served token."""
     import jax.numpy as jnp
-
-    from bench.reference import forward
 
     seq = np.concatenate([s.prompt, s.tokens[:-1]])
     if len(seq) > pad_to:
@@ -59,7 +58,7 @@ def _reference_rows(w, cfg, s: Served, pad_to: int, precision: str):
     padded = np.zeros(pad_to, np.int32)
     padded[: len(seq)] = seq
     p = len(s.prompt)
-    logits = forward(w, jnp.asarray(padded), cfg, precision)
+    logits = blocks.forward(w, jnp.asarray(padded), cfg, precision)
     return np.asarray(logits[p - 1: p - 1 + len(s.tokens)], np.float32)
 
 
@@ -70,11 +69,12 @@ def row_gaps(ref: np.ndarray, picks: np.ndarray) -> np.ndarray:
     return (best - chosen) / np.abs(ref).max(axis=1)
 
 
-def served_gap(w, cfg, served: Sequence[Served], pad_to: int) -> Tuple[float, int]:
+def served_gap(blocks, w, cfg, served: Sequence[Served],
+               pad_to: int) -> Tuple[float, int]:
     """(widest gap, tokens compared) of the served tokens."""
     widest, n = 0.0, 0
     for s in served:
-        ref = _reference_rows(w, cfg, s, pad_to, "f32")
+        ref = _reference_rows(blocks, w, cfg, s, pad_to, "f32")
         if not np.isfinite(ref).all():
             return float("inf"), n
         widest = max(widest, float(row_gaps(ref, s.tokens).max()))
@@ -82,21 +82,24 @@ def served_gap(w, cfg, served: Sequence[Served], pad_to: int) -> Tuple[float, in
     return widest, n
 
 
-def control_gaps(w, cfg, served: Sequence[Served], pad_to: int) -> Tuple[float, float]:
+def control_gaps(blocks, w, cfg, served: Sequence[Served],
+                 pad_to: int) -> Tuple[float, float]:
     """(served gap, control gap): the widest gap of the served tokens and
     of the tokens the fp8 reference puts first, both read in the float32
     reference."""
     served_w, control_w = 0.0, 0.0
     for s in served:
-        ref = _reference_rows(w, cfg, s, pad_to, "f32")
-        low = _reference_rows(w, cfg, s, pad_to, "fp8")
+        ref = _reference_rows(blocks, w, cfg, s, pad_to, "f32")
+        low = _reference_rows(blocks, w, cfg, s, pad_to, "fp8")
         served_w = max(served_w, float(row_gaps(ref, s.tokens).max()))
         control_w = max(control_w, float(row_gaps(ref, low.argmax(axis=1)).max()))
     return served_w, control_w
 
 
-def run(cfg, seed: int, served: Sequence[Served], geometry: Dict, device) -> Verdict:
-    """Rebuild the weights from the seed and judge the sample."""
+def run(blocks, cfg, seed: int, served: Sequence[Served], geometry: Dict,
+        device) -> Verdict:
+    """Rebuild the weights from the seed and judge the sample against the
+    reference of the block module ``blocks``."""
     import jax
 
     from bench.weights import make_weights
@@ -106,9 +109,9 @@ def run(cfg, seed: int, served: Sequence[Served], geometry: Dict, device) -> Ver
     if not served:
         return Verdict(False, checked)
     t0 = time.perf_counter()
-    w = make_weights(cfg, seed, device)
+    w = make_weights(blocks, cfg, seed, device)
     with jax.default_matmul_precision("highest"):
-        gap, n = served_gap(w, cfg, served, geometry["max_seq"])
+        gap, n = served_gap(blocks, w, cfg, served, geometry["max_seq"])
     del w
     print(f"bench: reference compared {n} served tokens of {len(served)} "
           f"requests in {time.perf_counter() - t0:.1f} s", file=sys.stderr,

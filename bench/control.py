@@ -6,7 +6,8 @@ the control's, on many seeds in one process.
 
 Each seed is a whole run of the cell (weights, warm-up from the
 persistent cache, the cell's own traffic and load for ``--seconds``);
-after it the served sample is read twice in the float32 reference: the
+after it the served sample is read twice in the float32 reference of the
+configuration's block module: the
 gap of the served tokens (the lower reading) and the gap of the tokens the
 fp8 reference puts first at the same positions (the control's reading,
 which has to come out above the limit).  Prints one line per seed and the
@@ -39,10 +40,11 @@ def readings(cell, seeds, seconds: float, devices, smoke: bool = False):
 
     out = []
 
-    def judge(cfg, seed, served, geometry, device):
-        w = make_weights(cfg, seed, device)
+    def judge(blocks, cfg, seed, served, geometry, device):
+        w = make_weights(blocks, cfg, seed, device)
         with jax.default_matmul_precision("highest"):
-            prog, ctrl = check.control_gaps(w, cfg, served, geometry["max_seq"])
+            prog, ctrl = check.control_gaps(blocks, w, cfg, served,
+                                            geometry["max_seq"])
         out.append((seed, prog, ctrl))
         return check.Verdict(prog <= geometry["gap_limit"], {
             "served_gap": {"value": prog, "limit": geometry["gap_limit"]}})

@@ -10,7 +10,6 @@ from __future__ import annotations
 
 import dataclasses
 import gc
-import importlib.util
 import math
 import shutil
 import sys
@@ -85,7 +84,8 @@ class CompileMeter:
 
 def build(cell: spec.Cell, cfg, seed: int, device, trace: bool):
     """(engine, clock): the engine at the cell's geometry with the
-    benchmark's weights on ``device``, and its span tracer's clock."""
+    benchmark's weights on ``device``, laid out by the configuration's
+    block module, and its span tracer's clock."""
     from bench.weights import check_layout, make_weights
 
     g = cell.geometry
@@ -94,9 +94,9 @@ def build(cell: spec.Cell, cfg, seed: int, device, trace: bool):
         cfg.name, smoke=False, max_batch=g["max_batch"],
         page_size=g["page_size"], max_seq=g["max_seq"],
         num_pages=g["num_pages"], seed=seed,
-        params=make_weights(cfg, seed, device), prefill_chunk=g["prefill_chunk"],
-        trace=trace, trace_clock=clock)
-    check_layout(cfg, eng.lm.param_shapes())
+        params=make_weights(cell.blocks, cfg, seed, device),
+        prefill_chunk=g["prefill_chunk"], trace=trace, trace_clock=clock)
+    check_layout(cell.blocks, cfg, eng.lm.param_shapes())
     return eng, clock
 
 
@@ -152,6 +152,7 @@ class Live:
     request: object = None      # the engine's Request
     times: List[float] = dataclasses.field(default_factory=list)
     finished: float = math.inf
+    caller: int = -1            # closed loop: the caller that sent it
 
     @property
     def generated(self) -> List[int]:
@@ -180,22 +181,30 @@ class Loop:
         self.steps: List[Step] = []
         self.refused = 0
 
-    def _send(self, due: float) -> None:
+    def _take(self) -> traffic.RequestSpec:
         if self.next >= len(self.specs):
             raise RuntimeError("the traffic ran out of requests; raise the "
                                "mix's count")
-        s = self.specs[self.next]
         self.next += 1
-        rec = Live(spec=s, due=due, sent=time.perf_counter())
+        return self.specs[self.next - 1]
+
+    def _send(self, due: float, caller: int = -1) -> bool:
+        """Send the next request, due at ``due``; False if it was refused."""
+        s = self._take()
+        rec = Live(spec=s, due=due, sent=time.perf_counter(), caller=caller)
         try:
             rec.request = self.engine.submit(s.prompt, s.max_new_tokens,
                                              arrival_step=self.engine.step_count)
         except ValueError as e:
             log(f"bench: request refused: {e}")
             self.refused += 1
-            return
+            return False
         self.live.append(rec)
         self.inflight.append(rec)
+        return True
+
+    def _finished(self, rec: Live) -> None:
+        """``rec`` received its last token at ``rec.finished``."""
 
     def _next_due(self) -> float:
         if self.next < len(self.specs):
@@ -228,6 +237,7 @@ class Loop:
                     lens.append(len(rec.spec.prompt) + n0)
             if n1 >= rec.spec.max_new_tokens:
                 rec.finished = t1
+                self._finished(rec)
             else:
                 keep.append(rec)
         self.inflight = keep
@@ -249,6 +259,49 @@ class Loop:
             nxt = min(t_end, self._next_due())
             with jax.profiler.TraceAnnotation("bench.wait"):
                 time.sleep(max(0.0, nxt - time.perf_counter()))
+
+
+class ClosedLoop(Loop):
+    """Serve the traffic closed loop: ``callers`` callers, each with at most
+    one request in flight.  A caller's first request falls due at its point
+    of an even spread over ``ramp_s``; each later one at the host-clock time
+    its previous one finished (the end of the step that produced its last
+    token), with no think time."""
+
+    def __init__(self, engine, specs, t_start, callers: int, ramp_s: float):
+        super().__init__(engine, [], t_start)
+        self.source = specs
+        # when each caller's next request falls due; inf while it waits
+        self.due = [t_start + i * ramp_s / callers for i in range(callers)]
+
+    def _take(self) -> traffic.RequestSpec:
+        return next(self.source)
+
+    def _next_due(self) -> float:
+        return min(self.due)
+
+    def _send_due(self, now: float) -> None:
+        while self._next_due() <= now:
+            due = self._next_due()
+            caller = self.due.index(due)
+            self.due[caller] = math.inf
+            if not self._send(due, caller):  # refused: the caller goes on
+                self.due[caller] = time.perf_counter()
+
+    def _finished(self, rec: Live) -> None:
+        self.due[rec.caller] = rec.finished
+
+
+def serving_loop(engine, cell: spec.Cell, vocab: int, seed: int,
+                 horizon_s: float) -> Loop:
+    """The loop that serves the cell's traffic mix, started now."""
+    mix, g = cell.traffic, cell.geometry
+    if mix["arrivals"] == "closed":
+        specs = traffic.requests(mix, g, vocab, seed)
+        return ClosedLoop(engine, specs, time.perf_counter(), g["callers"],
+                          g["ramp_s"])
+    specs = traffic.generate(mix, g, vocab, seed, horizon_s)
+    return Loop(engine, specs, time.perf_counter())
 
 
 # ---------------------------------------------------------------------------
@@ -319,11 +372,8 @@ class RunData:
 
 
 def load_reader(root: Path, name: str):
-    path = root / "bench" / "metrics" / f"{name}.py"
-    mod_spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read
+    return spec.load_module(root / "bench" / "metrics" / f"{name}.py",
+                            f"bench_metric_{name}").read
 
 
 def per_layer(cell: spec.Cell, data: RunData) -> Dict:
@@ -378,11 +428,8 @@ def run_cell(cell: spec.Cell, *, seed: int, seconds: float, trace: bool,
         f"loaded in {meter.seconds:.1f} s")
 
     ramp = g["ramp_s"]
-    specs = traffic.generate(cell.traffic, g, cfg.vocab_size, seed,
-                             ramp + seconds + 5.0)
-    t_start = time.perf_counter()
-    loop = Loop(engine, specs, t_start)
-    loop.run_until(t_start + ramp)
+    loop = serving_loop(engine, cell, cfg.vocab_size, seed, ramp + seconds + 5.0)
+    loop.run_until(loop.t_start + ramp)
     w0 = time.perf_counter()  # the window opens at a step boundary
     setup_s = w0 - t_process
     n_compiled = meter.n
@@ -439,7 +486,7 @@ def run_cell(cell: spec.Cell, *, seed: int, seconds: float, trace: bool,
     log(f"bench: memory peak {peak} bytes; "
         f"{(dev.memory_stats() or {}).get('bytes_in_use')} in use once the "
         "program's state is freed, before the reference")
-    verdict = (judge or check.run)(cfg, seed, sample, g, devices[0])
+    verdict = (judge or check.run)(cell.blocks, cfg, seed, sample, g, devices[0])
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(devices), "memory_peak_bytes": peak}
     result = {"correct": verdict.correct and in_window == 0,

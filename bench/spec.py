@@ -3,11 +3,16 @@
 ``BENCHMARK.json`` names each cell's configuration and traffic mix; the
 serving geometry of a cell lives in ``cells/<cell>.json``, the model sizes
 in the configuration's ``file`` and the traffic parameters in
-``traffic/<mix>.json``.  Nothing here knows a cell by name.
+``traffic/<mix>.json``.  The configuration's ``"blocks"`` names its block
+module, ``blocks/<name>.py`` (``dense`` when the file names none), which
+lays out the program's parameters and holds the plain reference.  Nothing
+here knows a cell by name.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
+import importlib.util
 import json
 from pathlib import Path
 from typing import Any, Dict
@@ -16,7 +21,9 @@ ROOT = Path(__file__).resolve().parents[1]
 
 # keys of a configuration file that describe it rather than set a size
 META_KEYS = ("name", "registry", "source", "reduced", "assumed",
-             "departures", "deployment", "published")
+             "departures", "deployment", "published", "blocks")
+# keys of a configuration file that size one of ArchConfig's sub-configs
+SUB_CONFIGS = ("moe", "mla", "mamba")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -29,6 +36,25 @@ class Cell:
     geometry: Dict[str, Any]   # cells/<cell>.json
     end_to_end: tuple          # metric entries the cell reports with --trace 0
     per_layer: tuple           # metric entries the cell reports with --trace 1
+
+    @functools.cached_property
+    def blocks(self):
+        """The configuration's block module: ``layout(cfg)`` and the
+        reference ``forward(w, tokens, cfg, precision)``."""
+        name = self.config.get("blocks", "dense")
+        path = self.root / "bench" / "blocks" / f"{name}.py"
+        if not path.is_file():
+            raise SystemExit(f"{self.config['name']}: no block module {name!r} "
+                             f"({path})")
+        return load_module(path, f"bench_blocks_{name}")
+
+
+def load_module(path: Path, name: str):
+    """The Python file ``path`` as a module of its own named ``name``."""
+    mod_spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod
 
 
 def load_json(path: Path) -> Any:
@@ -64,16 +90,35 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
 
 def arch_config(config: Dict[str, Any], smoke: bool = False):
     """The program's ``ArchConfig`` for a configuration file: the registry
-    entry with every size the file gives.  ``smoke`` shrinks it to the
+    entry with every size the file gives.  A key that names a sub-config
+    (``moe``, ``mla``, ``mamba``) takes a dict of that sub-config's fields,
+    applied to the registry entry's sub-config.  ``smoke`` shrinks it to the
     repo's CPU test widths (rehearsal and tests only)."""
     from repro.configs import get_config, get_smoke_config
 
     base = (get_smoke_config if smoke else get_config)(config["registry"])
     sizes = {k: v for k, v in config.items() if k not in META_KEYS}
+    nested = {k: sizes.pop(k) for k in SUB_CONFIGS if k in sizes}
     if smoke:
         # keep the smoke widths; take only what changes the math
         sizes = {k: v for k, v in sizes.items()
-                 if k in ("qkv_bias", "norm_eps", "rotary_pct", "rope_theta")}
-        return dataclasses.replace(base, **sizes)
+                 if k in ("qkv_bias", "norm_eps", "rotary_pct", "rope_theta",
+                          "dtype")}
+    else:
+        sizes["name"] = config["name"]
     cfg = dataclasses.replace(base, **sizes)
-    return dataclasses.replace(cfg, name=config["name"])
+    for key, fields in nested.items():
+        sub = getattr(cfg, key)
+        if sub is None:
+            raise SystemExit(f"{config['name']}: registry entry "
+                             f"{config['registry']!r} has no {key} sub-config")
+        known = {f.name for f in dataclasses.fields(sub)}
+        unknown = sorted(set(fields) - known)
+        if unknown:
+            raise SystemExit(f"{config['name']}: {key} has no field "
+                             f"{', '.join(unknown)}; known: {sorted(known)}")
+        if smoke:  # the smoke sub-config keeps its sizes (the integers)
+            fields = {k: v for k, v in fields.items()
+                      if isinstance(v, bool) or not isinstance(v, int)}
+        cfg = dataclasses.replace(cfg, **{key: dataclasses.replace(sub, **fields)})
+    return cfg

@@ -56,6 +56,11 @@ def main(argv=None) -> int:
         print("sweep: JAX found no TPU; nothing was run", file=sys.stderr)
         return 2
     cell = spec.load_cell(args.workload)
+    if cell.traffic["arrivals"] != "periodic_poisson":
+        print(f"sweep: {cell.name} is served by {cell.traffic['arrivals']} "
+              "arrivals; its load is its callers, not a rate, so it has no "
+              "knee to find", file=sys.stderr)
+        return 2
     use_compile_cache(cell.root)
     cfg = spec.arch_config(cell.config, args.smoke)
     engine, _ = build(cell, cfg, args.seed, jax.devices()[0], False)

@@ -1,12 +1,12 @@
 """Seeded weights, made by the benchmark on the device in one jitted call.
 
-The tree is laid out as the program's ``LM`` takes its parameters (an
-embedding, ``periods`` stacked on a leading layer axis, ``final_norm``,
-``lm_head``); the layout is written
-out here from the configuration, not read from the program, and
-``check_layout`` refuses a program whose tree differs.  The same seed gives
-the same values, so the reference rebuilds them after the program's state
-is freed.
+The tree is laid out as the program's ``LM`` takes its parameters; the
+configuration's block module (``bench/blocks/<name>.py``, found through
+``spec.Cell.blocks``) writes that layout out from the configuration, not
+read from the program, and ``check_layout`` refuses a program whose tree
+differs.  Each leaf's values follow from its path and shape alone.  The
+same seed gives the same values, so the reference rebuilds them after the
+program's state is freed.
 """
 from __future__ import annotations
 
@@ -16,43 +16,6 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-
-def _attention(cfg) -> Dict[str, Tuple[int, ...]]:
-    d, h, k, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    out = {"wq": (d, h * hd), "wk": (d, k * hd), "wv": (d, k * hd),
-           "wo": (h * hd, d)}
-    if cfg.qkv_bias:
-        out.update(bq=(h * hd,), bk=(k * hd,), bv=(k * hd,))
-    if cfg.qk_norm:
-        out.update(q_norm=(hd,), k_norm=(hd,))
-    return out
-
-
-def _block(cfg) -> Dict:
-    d = cfg.d_model
-    ffn = {"w_gate": (d, cfg.d_ff), "w_up": (d, cfg.d_ff),
-           "w_down": (cfg.d_ff, d)}
-    return {"ln1": (d,), "mixer": _attention(cfg), "ln2": (d,), "ffn": ffn}
-
-
-def layout(cfg) -> Dict:
-    """Shape tree of the parameters (tuples of ints at the leaves)."""
-    if any(s.mixer != "attn" or s.ffn != "dense" for s in cfg.period) \
-            or len(cfg.period) != 1 or cfg.mla is not None or cfg.first_k_dense:
-        raise ValueError(f"{cfg.name}: the benchmark serves attention "
-                         "layers with a dense FFN, one per period")
-    n = cfg.n_periods
-    period = jax.tree.map(lambda s: (n,) + s, _block(cfg),
-                          is_leaf=lambda x: isinstance(x, tuple))
-    tree = {
-        "embed": (cfg.vocab_size, cfg.d_model),
-        "final_norm": (cfg.d_model,),
-        "periods": {"pos0": period},
-    }
-    if not cfg.tie_embeddings:
-        tree["lm_head"] = (cfg.d_model, cfg.vocab_size)
-    return tree
 
 
 def _is_shape(x) -> bool:
@@ -98,10 +61,10 @@ def _build(tree, values, prefix=""):
     return tuple(_build(v, values, f"{prefix}/{i}") for i, v in enumerate(tree))
 
 
-def make_weights(cfg, seed: int, device=None) -> Dict:
-    """Every parameter from ``seed``, float32, in one jitted call placed on
-    ``device`` (the default device when None)."""
-    tree = layout(cfg)
+def make_weights(blocks, cfg, seed: int, device=None) -> Dict:
+    """Every parameter of ``blocks.layout(cfg)`` from ``seed``, float32, in
+    one jitted call placed on ``device`` (the default device when None)."""
+    tree = blocks.layout(cfg)
     paths = _paths(tree)
 
     def make(key):
@@ -115,9 +78,9 @@ def make_weights(cfg, seed: int, device=None) -> Dict:
     return jax.jit(make, out_shardings=out_sh)(seed_key(seed))
 
 
-def check_layout(cfg, program_shapes) -> None:
+def check_layout(blocks, cfg, program_shapes) -> None:
     """Refuse a program whose parameter tree is not the one made here."""
-    ours = jax.tree.map(lambda s: tuple(s), layout(cfg), is_leaf=_is_shape)
+    ours = jax.tree.map(lambda s: tuple(s), blocks.layout(cfg), is_leaf=_is_shape)
     theirs = jax.tree.map(lambda s: tuple(s.shape), program_shapes)
     if jax.tree.structure(ours, is_leaf=_is_shape) != \
             jax.tree.structure(theirs, is_leaf=_is_shape) or \
