@@ -44,6 +44,21 @@ class MoEConfig:
     router_aux_loss: float = 0.001
     # normalise top-k router weights to sum to one (deepseek-style)
     norm_topk: bool = True
+    # routed experts this chip holds under expert parallelism: experts
+    # 0 .. held_experts-1 of the n_routed_experts the router scores
+    # (0 = all of them)
+    held_experts: int = 0
+
+    def __post_init__(self):
+        if not 0 <= self.held_experts <= self.n_routed_experts:
+            raise ValueError(
+                f"held_experts={self.held_experts} not in "
+                f"[0, n_routed_experts={self.n_routed_experts}]")
+
+    @property
+    def n_held(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.held_experts or self.n_routed_experts
 
 
 @dataclass(frozen=True)
@@ -185,7 +200,7 @@ class ArchConfig:
         per_expert = 3 * self.d_model * moe.expert_d_ff
         router = self.d_model * moe.n_routed_experts
         shared = moe.n_shared_experts * per_expert
-        routed = (moe.top_k if active_only else moe.n_routed_experts) * per_expert
+        routed = (moe.top_k if active_only else moe.n_held) * per_expert
         return router + shared + routed
 
     def param_count(self, active_only: bool = False) -> int:

@@ -53,6 +53,21 @@ def block_cache_axes(cfg: ArchConfig, spec: LayerSpec) -> Dict:
     return dict(mamba_mod.MAMBA_CACHE_AXES)
 
 
+def _apply_ffn(p: Dict, x: jnp.ndarray, cfg: ArchConfig, spec: LayerSpec,
+               rt: Runtime, *, train: bool):
+    """x + the layer's FFN of x; returns (x, aux_loss, counts), counts the
+    MoE dispatch's int32 [routed_rows, expert_rows] (None without MoE)."""
+    aux = jnp.zeros((), jnp.float32)
+    if spec.ffn == "none":
+        return x, aux, None
+    h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
+    if spec.ffn == "dense":
+        return x + apply_mlp(p["ffn"], h2, cfg.dtype, rt.constrain_fn), aux, None
+    y2, aux, counts = moe_mod.apply_moe(
+        p["ffn"], h2, cfg, train=train, mesh=rt.mesh, rules=rt.rules)
+    return x + y2, aux, counts
+
+
 def apply_block(
     p: Dict,
     x: jnp.ndarray,
@@ -64,7 +79,6 @@ def apply_block(
     kv_lens: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, Optional[Dict], jnp.ndarray]:
     """Returns (x, cache-or-None, aux_loss)."""
-    aux = jnp.zeros((), jnp.float32)
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
     if spec.mixer == "attn":
         if _uses_mla(cfg):
@@ -83,15 +97,7 @@ def apply_block(
             scan_chunk=rt.scan_chunk)
     x = x + y
     x = rt.constrain(x, ("batch", "seq", "act_embed")) if rt.rules else x
-    if spec.ffn != "none":
-        h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-        if spec.ffn == "dense":
-            y2 = apply_mlp(p["ffn"], h2, cfg.dtype, rt.constrain_fn)
-        else:
-            y2, aux = moe_mod.apply_moe(
-                p["ffn"], h2, cfg, train=(mode == "train"), mesh=rt.mesh,
-                rules=rt.rules)
-        x = x + y2
+    x, aux, _ = _apply_ffn(p, x, cfg, spec, rt, train=(mode == "train"))
     return x, cache, aux
 
 
@@ -104,10 +110,11 @@ def apply_block_decode_paged(
     cache: Dict,
     lengths: jnp.ndarray,
     page_tables: jnp.ndarray,  # (B, pages_per_seq) physical page ids
-) -> Tuple[jnp.ndarray, Dict]:
+) -> Tuple[jnp.ndarray, Dict, Optional[jnp.ndarray]]:
     """Decode step against a paged cache: attention/MLA leaves are page-major
     ((n_pages, ..., page_size, ...)); mamba state leaves are slot-major and
-    use the regular decode path unchanged."""
+    use the regular decode path unchanged.  Returns (x, cache, MoE counts
+    or None)."""
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
     if spec.mixer == "attn":
         if _uses_mla(cfg):
@@ -124,16 +131,8 @@ def apply_block_decode_paged(
     else:
         y, new_cache = mamba_mod.apply_mamba_decode(
             p["mixer"], h, cfg, cache, constrain_fn=rt.constrain_fn)
-    x = x + y
-    if spec.ffn != "none":
-        h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-        if spec.ffn == "dense":
-            y2 = apply_mlp(p["ffn"], h2, cfg.dtype, rt.constrain_fn)
-        else:
-            y2, _ = moe_mod.apply_moe(
-                p["ffn"], h2, cfg, train=False, mesh=rt.mesh, rules=rt.rules)
-        x = x + y2
-    return x, new_cache
+    x, _, counts = _apply_ffn(p, x + y, cfg, spec, rt, train=False)
+    return x, new_cache, counts
 
 
 def apply_block_prefill_paged(
@@ -147,10 +146,11 @@ def apply_block_prefill_paged(
     page_tables: jnp.ndarray,  # (1, pages_per_seq)
     *,
     s0: int,  # static absolute position of the chunk's first token
-) -> Tuple[jnp.ndarray, Dict]:
+) -> Tuple[jnp.ndarray, Dict, Optional[jnp.ndarray]]:
     """Chunked-prefill step against a paged cache.  Attention-only archs:
     mamba's slot-major recurrent state has no paged/positional form, so the
-    engine gates chunked prefill to attn mixers (see ServeEngine)."""
+    engine gates chunked prefill to attn mixers (see ServeEngine).  Returns
+    (x, cache, MoE counts or None)."""
     if spec.mixer != "attn":
         raise NotImplementedError(
             "chunked paged prefill supports attn mixers only")
@@ -165,16 +165,8 @@ def apply_block_prefill_paged(
             p["mixer"], h, cfg, cache, n_valid, page_tables,
             s0=s0, page_size=rt.page_size, block_q=rt.block_q,
             block_k=rt.block_k)
-    x = x + y
-    if spec.ffn != "none":
-        h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-        if spec.ffn == "dense":
-            y2 = apply_mlp(p["ffn"], h2, cfg.dtype, rt.constrain_fn)
-        else:
-            y2, _ = moe_mod.apply_moe(
-                p["ffn"], h2, cfg, train=False, mesh=rt.mesh, rules=rt.rules)
-        x = x + y2
-    return x, new_cache
+    x, _, counts = _apply_ffn(p, x + y, cfg, spec, rt, train=False)
+    return x, new_cache, counts
 
 
 def apply_block_decode(
@@ -199,13 +191,5 @@ def apply_block_decode(
     else:
         y, new_cache = mamba_mod.apply_mamba_decode(
             p["mixer"], h, cfg, cache, constrain_fn=rt.constrain_fn)
-    x = x + y
-    if spec.ffn != "none":
-        h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-        if spec.ffn == "dense":
-            y2 = apply_mlp(p["ffn"], h2, cfg.dtype, rt.constrain_fn)
-        else:
-            y2, _ = moe_mod.apply_moe(
-                p["ffn"], h2, cfg, train=False, mesh=rt.mesh, rules=rt.rules)
-        x = x + y2
+    x, _, _ = _apply_ffn(p, x + y, cfg, spec, rt, train=False)
     return x, new_cache

@@ -35,6 +35,14 @@ from repro.models.param import ann, split_tree, stack_periods
 from repro.models.runtime import Runtime
 
 
+def _tally(counts, layer_counts):
+    return counts if layer_counts is None else counts + layer_counts
+
+
+def _with_counts(out: Tuple, counts) -> Tuple:
+    return out if counts is None else out + (counts,)
+
+
 class LM:
     def __init__(self, cfg: ArchConfig, rt: Optional[Runtime] = None):
         self.cfg = cfg
@@ -177,6 +185,11 @@ class LM:
     # ------------------------------------------------------------------
     # Serving
     # ------------------------------------------------------------------
+    def _no_counts(self):
+        """Start of the serving steps' MoE work counts: zeros for a
+        configuration with MoE layers, else None (no extra output)."""
+        return jnp.zeros((2,), jnp.int32) if self.cfg.uses_moe else None
+
     def prefill(self, params, tokens: jnp.ndarray,
                 frontend_embeds: Optional[jnp.ndarray] = None):
         cfg = self.cfg
@@ -268,32 +281,39 @@ class LM:
         pages then attends causally with ``q_offset=s0`` over the gathered
         page row, so after the final chunk the pages and the last-position
         logits are bitwise those of a monolithic prefill (see DESIGN.md §11).
-        Returns (logits (1, C, V), new_cache)."""
+        Returns (logits (1, C, V), new_cache), and for a configuration with
+        MoE layers a third output, the int32 [routed_rows, expert_rows]
+        summed over them (``models/moe.py``)."""
         cfg, rt = self.cfg, self.rt
         x = embed_tokens(params["embed"], tokens, cfg.dtype)  # (1, C, d)
+        counts = self._no_counts()
         new_head = []
         for hp, hc in zip(params.get("head_layers", ()), cache["head"]):
-            x, c = blocks_mod.apply_block_prefill_paged(
+            x, c, n = blocks_mod.apply_block_prefill_paged(
                 hp, x, cfg, self._head_spec(), rt, hc, n_valid, page_tables,
                 s0=s0)
             new_head.append(c)
+            counts = _tally(counts, n)
 
-        def period_fn(x, inputs):
+        def period_fn(carry, inputs):
+            x, counts = carry
             period_params, cache_in = inputs
             new_caches = {}
             for i, spec in enumerate(cfg.period):
-                x, c = blocks_mod.apply_block_prefill_paged(
+                x, c, n = blocks_mod.apply_block_prefill_paged(
                     period_params[f"pos{i}"], x, cfg, spec, rt,
                     cache_in[f"pos{i}"], n_valid, page_tables, s0=s0)
                 new_caches[f"pos{i}"] = c
-            return x, new_caches
+                counts = _tally(counts, n)
+            return (x, counts), new_caches
 
-        x, new_periods = lax.scan(period_fn, x,
-                                  (params["periods"], cache["periods"]))
+        (x, counts), new_periods = lax.scan(
+            period_fn, (x, counts), (params["periods"], cache["periods"]))
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
         logits = lm_logits(head, x, cfg.dtype)  # (1, C, V)
-        return logits, {"head": tuple(new_head), "periods": new_periods}
+        return _with_counts(
+            (logits, {"head": tuple(new_head), "periods": new_periods}), counts)
 
     def decode_step_paged(self, params, tokens: jnp.ndarray,
                           lengths: jnp.ndarray, cache: Dict,
@@ -311,28 +331,34 @@ class LM:
         kernels/flash_decode/ops.py; ``ServeEngine`` fills it in from the
         backend and mesh); ``Runtime.pages_per_program`` defaults to
         ``DEFAULT_PAGES_PER_PROGRAM`` there.  Returns (logits (B,V),
-        new_cache)."""
+        new_cache), and for a configuration with MoE layers a third output
+        as ``prefill_chunk`` does."""
         cfg, rt = self.cfg, self.rt
         x = embed_tokens(params["embed"], tokens[:, None], cfg.dtype)
+        counts = self._no_counts()
         new_head = []
         for hp, hc in zip(params.get("head_layers", ()), cache["head"]):
-            x, c = blocks_mod.apply_block_decode_paged(
+            x, c, n = blocks_mod.apply_block_decode_paged(
                 hp, x, cfg, self._head_spec(), rt, hc, lengths, page_tables)
             new_head.append(c)
+            counts = _tally(counts, n)
 
-        def period_fn(x, inputs):
+        def period_fn(carry, inputs):
+            x, counts = carry
             period_params, cache_in = inputs
             new_caches = {}
             for i, spec in enumerate(cfg.period):
-                x, c = blocks_mod.apply_block_decode_paged(
+                x, c, n = blocks_mod.apply_block_decode_paged(
                     period_params[f"pos{i}"], x, cfg, spec, rt,
                     cache_in[f"pos{i}"], lengths, page_tables)
                 new_caches[f"pos{i}"] = c
-            return x, new_caches
+                counts = _tally(counts, n)
+            return (x, counts), new_caches
 
-        x, new_periods = lax.scan(period_fn, x,
-                                  (params["periods"], cache["periods"]))
+        (x, counts), new_periods = lax.scan(
+            period_fn, (x, counts), (params["periods"], cache["periods"]))
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
         logits = lm_logits(head, x[:, 0], cfg.dtype)
-        return logits, {"head": tuple(new_head), "periods": new_periods}
+        return _with_counts(
+            (logits, {"head": tuple(new_head), "periods": new_periods}), counts)

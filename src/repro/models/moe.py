@@ -15,8 +15,20 @@ Jamba.  The dispatch-compute-combine path is written once and run two ways:
 * **local path** (single host / smoke tests): identical math, E_local = E,
   no psum.
 
+``MoEConfig.held_experts`` < E gives this chip the share one chip holds in
+an expert-parallel deployment: the router still scores all E experts and
+picks the top-k among them, but only experts 0 .. held-1 have weights here
+and only their part of the result is computed (routed ids past them drop,
+as in the EP path); the shared experts are added once.  Nothing stands in
+for the other chips' experts or their exchange.
+
 Capacity-overflow tokens are dropped per expert (standard Switch/GShard
 semantics); the router aux loss keeps load balanced.
+
+Each dispatch also counts its work: ``apply_moe`` returns, beside the
+output, int32 ``[routed_rows, expert_rows]``: the (token, expert) pairs
+routed to held experts within capacity, and the rows the expert FFN
+computes (held experts x capacity).
 """
 from __future__ import annotations
 
@@ -36,16 +48,17 @@ from repro.models.param import ann
 def init_moe(key: jax.Array, cfg: ArchConfig) -> Dict:
     moe = cfg.moe
     d, e, f = cfg.d_model, moe.n_routed_experts, moe.expert_d_ff
+    h = moe.n_held
     keys = jax.random.split(key, 7)
     s_in, s_ff = 1.0 / math.sqrt(d), 1.0 / math.sqrt(f)
     p = {
         "router": ann(jax.random.normal(keys[0], (d, e), jnp.float32) * s_in,
                       "embed", "expert"),
-        "w_gate": ann(jax.random.normal(keys[1], (e, d, f), jnp.float32) * s_in,
+        "w_gate": ann(jax.random.normal(keys[1], (h, d, f), jnp.float32) * s_in,
                       "expert", "embed", "expert_mlp"),
-        "w_up": ann(jax.random.normal(keys[2], (e, d, f), jnp.float32) * s_in,
+        "w_up": ann(jax.random.normal(keys[2], (h, d, f), jnp.float32) * s_in,
                     "expert", "embed", "expert_mlp"),
-        "w_down": ann(jax.random.normal(keys[3], (e, f, d), jnp.float32) * s_ff,
+        "w_down": ann(jax.random.normal(keys[3], (h, f, d), jnp.float32) * s_ff,
                       "expert", "expert_mlp", "embed"),
     }
     if moe.n_shared_experts:
@@ -80,9 +93,13 @@ def _capacity(t: int, moe, train: bool) -> int:
 
 
 def _route(p: Dict, x: jnp.ndarray, cfg: ArchConfig, train: bool):
-    """Router in fp32. x (B,S,d) -> ids (B,S,k) int32, probs (B,S,k) f32, aux."""
+    """Router in fp32 over all E experts (at ``HIGHEST``: a TPU runs a
+    float32 matmul in bfloat16 passes by default, and the gate's top-k is
+    where rounding flips a choice).  x (B,S,d) -> ids (B,S,k) int32, probs
+    (B,S,k) f32, aux."""
     moe = cfg.moe
-    logits = x.astype(jnp.float32) @ p["router"].astype(jnp.float32)
+    logits = jnp.matmul(x.astype(jnp.float32), p["router"].astype(jnp.float32),
+                        precision=lax.Precision.HIGHEST)
     probs_full = jax.nn.softmax(logits, axis=-1)
     probs, ids = lax.top_k(probs_full, moe.top_k)
     if moe.norm_topk:
@@ -194,6 +211,21 @@ def _shared_ffn(xt, sh_g, sh_u, sh_d, dtype) -> jnp.ndarray:
     return h @ cast_to(sh_d, dtype)
 
 
+def _counts(ids: jnp.ndarray, held: int, n_blocks: int,
+            capacity: int) -> jnp.ndarray:
+    """int32 [routed_rows, expert_rows] of ``n_blocks`` equal dispatches
+    over ``ids`` (..., k): (token, expert) pairs on held experts that fit
+    each block's capacity, and the held x capacity rows each block
+    computes."""
+    ids = ids.reshape(n_blocks, -1)
+    block = jnp.arange(n_blocks, dtype=jnp.int32)[:, None]
+    bucket = jnp.where(ids < held, block * held + ids, n_blocks * held)
+    per_expert = jnp.bincount(bucket.reshape(-1), length=n_blocks * held + 1)
+    routed = jnp.minimum(per_expert[:-1], capacity).sum()
+    return jnp.stack([routed, jnp.asarray(n_blocks * held * capacity)]
+                     ).astype(jnp.int32)
+
+
 def apply_moe(
     p: Dict,
     x: jnp.ndarray,  # (B, S, d)
@@ -202,10 +234,12 @@ def apply_moe(
     train: bool,
     mesh=None,
     rules=None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Returns (y (B,S,d), aux_loss scalar)."""
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Returns (y (B,S,d), aux_loss scalar, counts int32 [routed_rows,
+    expert_rows])."""
     moe = cfg.moe
     b, s, d = x.shape
+    held = moe.n_held
     ids, probs, aux = _route(p, x, cfg, train)
     has_shared = moe.n_shared_experts > 0
     use_shard_map = mesh is not None and rules is not None and \
@@ -220,10 +254,18 @@ def apply_moe(
         if has_shared:
             y = y + _shared_ffn(x.reshape(t, d), p["sh_gate"], p["sh_up"],
                                 p["sh_down"], cfg.dtype)
-        return y.reshape(b, s, d), aux
+        return y.reshape(b, s, d), aux, _counts(ids, held, 1, cap)
 
     model_axis = rules.model_axis()
     batch_axes = rules.batch_axes()
+    n_model = rules.axis_sizes[model_axis]
+    if held % n_model:
+        raise ValueError(
+            f"{cfg.name}: {held} held experts do not split over the "
+            f"{n_model}-way {model_axis!r} mesh axis")
+    n_blocks = math.prod(rules.axis_sizes[a] for a in batch_axes)
+    counts = _counts(ids, held, n_blocks,
+                     _capacity(b * s // n_blocks, moe, train))
     bspec = tuple(batch_axes) if len(batch_axes) > 1 else (
         batch_axes[0] if batch_axes else None)
     # data axes NOT carrying the batch can carry the experts' d_model dim
@@ -285,4 +327,4 @@ def apply_moe(
         args += [p["sh_gate"], p["sh_up"], p["sh_down"]]
     y = jax.shard_map(fn, mesh=mesh, in_specs=tuple(in_specs),
                       out_specs=P(bspec, None, None), check_vma=False)(*args)
-    return y, aux
+    return y, aux, counts

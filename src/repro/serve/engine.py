@@ -401,8 +401,8 @@ class ServeEngine:
             rid=req.rid,
             tokens=n_tokens,
             s0=s0,
-        ):
-            logits_dev, self.cache = self._chunk(
+        ) as sp:
+            logits_dev, self.cache, *counts_dev = self._chunk(
                 self.params,
                 jnp.asarray(chunk)[None],
                 jnp.int32(n_tokens),
@@ -411,12 +411,28 @@ class ServeEngine:
                 s0=s0,
             )
             logits_dev.block_until_ready()
+            _, counts = self._fetch(None, counts_dev, sp)
         dt = time.perf_counter() - t0
         req.prefill_s += dt
         req.prefill_pos += n_tokens
-        self._emit("prefill", batch=0, step_s=dt, prefill_tokens=n_tokens)
+        self._emit("prefill", batch=0, step_s=dt, prefill_tokens=n_tokens,
+                   **counts)
         if req.prefill_pos >= len(req.prompt):
             self._finish_prefill(req, logits_dev, (0, n_tokens - 1), 0)
+
+    def _fetch(self, logits_dev, counts_dev, span):
+        """(logits on the host, MoE counts): with tracing on, a step's
+        ``[routed_rows, expert_rows]`` (``counts_dev``, the step's extra
+        output; empty without MoE layers) come to the host with the logits
+        and are set on ``span``; with tracing off nothing more is fetched
+        and the counts are ``{}``."""
+        if span is None or not counts_dev:
+            logits = None if logits_dev is None else np.asarray(logits_dev)
+            return logits, {}
+        logits, (routed, rows) = jax.device_get((logits_dev, counts_dev[0]))
+        counts = {"routed_rows": int(routed), "expert_rows": int(rows)}
+        span.set(**counts)
+        return logits, counts
 
     def _release_slot(self, slot: int) -> None:
         self.lengths[slot] = 0
@@ -471,9 +487,11 @@ class ServeEngine:
             self.step_count += 1
             return n
         t0 = time.perf_counter()
-        with self._sp("decode", component="engine.decode", batch=len(decoding)):
+        with self._sp(
+            "decode", component="engine.decode", batch=len(decoding)
+        ) as sp:
             with self._sp("launch", component="engine.decode.launch"):
-                logits_dev, self.cache = self._decode(
+                logits_dev, self.cache, *counts_dev = self._decode(
                     self.params,
                     jnp.asarray(self.next_tokens),
                     jnp.asarray(self.lengths),
@@ -483,10 +501,14 @@ class ServeEngine:
             with self._sp("wait", component="engine.decode.wait"):
                 logits_dev.block_until_ready()
             with self._sp("fetch", component="engine.decode.fetch"):
-                logits_np = np.asarray(logits_dev)
+                logits_np, counts = self._fetch(logits_dev, counts_dev, sp)
         dt = time.perf_counter() - t0
         self._emit(
-            "decode", batch=len(decoding), step_s=dt, committed=len(decoding)
+            "decode",
+            batch=len(decoding),
+            step_s=dt,
+            committed=len(decoding),
+            **counts,
         )
         with self._sp("sample", component="engine.sample", batch=len(decoding)):
             for req in decoding:
@@ -567,7 +589,7 @@ class ServeEngine:
             batch=len(decoding),
             rows=n_rows,
         ):
-            logits_dev, self.cache = self._decode(
+            logits_dev, self.cache, *_ = self._decode(
                 self.params,
                 jnp.asarray(toks),
                 jnp.asarray(lens),
@@ -627,6 +649,8 @@ class ServeEngine:
         committed: int = 0,
         drafted: int = 0,
         prefill_tokens: int = 0,
+        routed_rows: int = 0,
+        expert_rows: int = 0,
     ) -> None:
         self._t_s += step_s
         self.tracker.emit(
@@ -640,6 +664,8 @@ class ServeEngine:
                 prefill_tokens=prefill_tokens,
                 t_s=self._t_s,
                 replica=self.replica_id,
+                routed_rows=routed_rows,
+                expert_rows=expert_rows,
             )
         )
 

@@ -113,16 +113,22 @@ class ShardingPlan:
     def decode_jit(self, lm, params: Any, cache: Any):
         """``LM.decode_step_paged`` jitted with explicit shardings:
         (params, tokens, lengths, cache, page_tables) -> (logits, cache),
-        cache donated, logits replicated (the engine argmaxes on host)."""
+        cache donated, logits replicated (the engine argmaxes on host), and
+        the MoE counts replicated where the model has them."""
         param_sh = self.param_sharding_tree(params, lm.param_axes())
         cache_sh = self.cache_sharding_tree(cache, lm.cache_axes())
         rep = self.replicated()
         return jax.jit(
             lm.decode_step_paged,
             in_shardings=(param_sh, rep, rep, cache_sh, rep),
-            out_shardings=(rep, cache_sh),
+            out_shardings=self._step_outputs(lm, cache_sh),
             donate_argnums=(3,),
         )
+
+    def _step_outputs(self, lm, cache_sh):
+        """Out shardings of a serving step: logits, cache, MoE counts."""
+        rep = self.replicated()
+        return (rep, cache_sh) + ((rep,) if lm.cfg.uses_moe else ())
 
     def prefill_chunk_jit(self, lm, params: Any, cache: Any):
         """``LM.prefill_chunk`` jitted with the same cache placement (chunk
@@ -139,7 +145,7 @@ class ShardingPlan:
             ),
             static_argnums=(5,),
             in_shardings=(param_sh, rep, rep, cache_sh, rep),
-            out_shardings=(rep, cache_sh),
+            out_shardings=self._step_outputs(lm, cache_sh),
             donate_argnums=(3,),
         )
 

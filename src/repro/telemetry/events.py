@@ -178,6 +178,11 @@ class ServeStepEvent(Event):
     # unchanged, and ``to_legacy`` never emits it (the pre-bus row shape
     # predates multi-replica serving).
     replica: int = -1
+    # MoE work of the step, summed over its MoE layers, when the engine
+    # traces (0 otherwise): (token, expert) pairs routed to held experts,
+    # and the expert rows the FFN computed.  Additive like ``replica``.
+    routed_rows: int = 0
+    expert_rows: int = 0
 
     @classmethod
     def from_legacy_row(cls, row: dict) -> "ServeStepEvent":

@@ -30,13 +30,13 @@ params, _ = split_tree(params_ann)
 x = jax.random.normal(jax.random.PRNGKey(1), (2, 1, cfg.d_model), jnp.float32) * 0.3
 
 # reference: local path (no mesh)
-y_ref, _ = moe_mod.apply_moe(params, x, cfg, train=False)
+y_ref, _, _ = moe_mod.apply_moe(params, x, cfg, train=False)
 
 # 2D path: mesh (4 data x 2 model), batch axes overridden to None
 mesh = make_debug_mesh(4, 2)
 rules = Rules.default(mesh).override(acts={"batch": None})
 with mesh:
-    y_2d, _ = jax.jit(lambda p, xx: moe_mod.apply_moe(
+    y_2d, _, _ = jax.jit(lambda p, xx: moe_mod.apply_moe(
         p, xx, cfg, train=False, mesh=mesh, rules=rules))(params, x)
 err = float(jnp.abs(y_2d - y_ref).max())
 rel = err / float(jnp.abs(y_ref).max())
@@ -45,10 +45,10 @@ assert rel < 2e-2, (err, rel)
 # standard EP path (batch sharded) must also agree
 rules_b = Rules.default(mesh)
 with mesh:
-    y_ep, _ = jax.jit(lambda p, xx: moe_mod.apply_moe(
+    y_ep, _, _ = jax.jit(lambda p, xx: moe_mod.apply_moe(
         p, xx, cfg, train=False, mesh=mesh, rules=rules_b))(
         params, jnp.tile(x, (4, 1, 1)))
-y_ref4, _ = moe_mod.apply_moe(params, jnp.tile(x, (4, 1, 1)), cfg, train=False)
+y_ref4, _, _ = moe_mod.apply_moe(params, jnp.tile(x, (4, 1, 1)), cfg, train=False)
 err2 = float(jnp.abs(y_ep - y_ref4).max())
 rel2 = err2 / float(jnp.abs(y_ref4).max())
 assert rel2 < 2e-2, (err2, rel2)

@@ -266,6 +266,90 @@ def test_engine_without_tracing_emits_no_span_and_same_tokens():
     np.testing.assert_array_equal(off.lengths, on.lengths)
 
 
+# ------------------------------------------------------- MoE work counters
+MOE_HELD = 4  # of the smoke model's 8 routed experts
+
+
+class _HeldMoEEngine(ServeEngine):
+    """The smoke DeepSeekMoE with a share of its routed experts held."""
+
+    @staticmethod
+    def config_for(arch, smoke):
+        import dataclasses
+
+        cfg = ServeEngine.config_for(arch, smoke)
+        return dataclasses.replace(cfg, moe=dataclasses.replace(
+            cfg.moe, held_experts=MOE_HELD))
+
+
+def _serve_moe(trace: bool):
+    eng = _HeldMoEEngine("deepseek-moe-16b", prefill_chunk=4, trace=trace,
+                         **GEOM)
+    rng = np.random.RandomState(7)
+    eng.submit(_prompt(rng, 11), max_new_tokens=3)
+    eng.submit(_prompt(rng, 6), max_new_tokens=5)
+    eng.run()
+    return eng
+
+
+def test_traced_moe_engine_counts_routed_and_computed_expert_rows():
+    eng = _serve_moe(trace=True)
+    n_moe = eng.cfg.n_periods  # one MoE layer a period after the dense head
+    rows = {"prefill": eng.prefill_chunk, "decode": eng.max_batch}
+    steps = eng.events("serve_step")
+    assert {e.op for e in steps} == {"prefill", "decode"}
+    for e in steps:
+        # dropless: each held expert's buffer holds every row of the dispatch
+        assert e.expert_rows == MOE_HELD * rows[e.op] * n_moe
+        assert 0 <= e.routed_rows <= e.expert_rows
+    assert sum(e.routed_rows for e in steps) > 0
+    spans = {e.component: e for e in eng.events("span")
+             if e.component in ("engine.decode", "engine.prefill_chunk")}
+    for comp, op in (("engine.decode", "decode"),
+                     ("engine.prefill_chunk", "prefill")):
+        assert spans[comp].attrs["expert_rows"] == MOE_HELD * rows[op] * n_moe
+        assert 0 <= spans[comp].attrs["routed_rows"] <= \
+            spans[comp].attrs["expert_rows"]
+
+
+def test_untraced_moe_engine_fetches_no_counts():
+    eng = _serve_moe(trace=False)
+    assert all(e.routed_rows == e.expert_rows == 0
+               for e in eng.events("serve_step"))
+
+
+def test_only_moe_serving_steps_have_the_counts_output():
+    import jax
+
+    def n_outputs(eng):
+        b = eng.max_batch
+        out = jax.eval_shape(
+            eng.lm.decode_step_paged, eng.params, np.zeros(b, np.int32),
+            np.zeros(b, np.int32), eng.cache, eng.page_tables)
+        chunk = jax.eval_shape(
+            lambda *a: eng.lm.prefill_chunk(*a, s0=0), eng.params,
+            np.zeros((1, 4), np.int32), np.int32(4), eng.cache,
+            eng.page_tables[:1])
+        return len(out), len(chunk)
+
+    assert n_outputs(ServeEngine(ARCH, **GEOM)) == (2, 2)
+    assert n_outputs(_HeldMoEEngine("deepseek-moe-16b", **GEOM)) == (3, 3)
+
+
+def test_serve_step_rows_without_counts_still_parse_and_legacy_is_unchanged():
+    from repro.telemetry import ServeStepEvent, from_dict
+
+    old = {"kind": "serve_step", "v": 1, "step": 3, "step_s": 0.5,
+           "op": "decode", "batch": 2, "committed": 2}
+    ev = from_dict(old)
+    assert (ev.routed_rows, ev.expert_rows) == (0, 0)
+    counted = ServeStepEvent(step=3, step_s=0.5, op="decode", batch=2,
+                             committed=2, routed_rows=5, expert_rows=40)
+    assert counted.to_legacy() == ev.to_legacy() == {
+        "step": 3, "batch": 2, "step_s": 0.5, "kind": "decode", "committed": 2}
+    assert from_dict(counted.to_dict()) == counted
+
+
 # ---------------------------------------------------------- capacity planner
 def test_capacity_planner_fit_query_roundtrip():
     # synthetic telemetry from a known affine step model t(b) = a + c*b
